@@ -5,9 +5,11 @@ sequential per-device placement costs grow linearly with the device count
 while one multicast placement stays ~flat; completion detection via the
 credit counter is one host interaction vs one per device for polling.
 
-Runs in a subprocess with N virtual host devices (the parent process keeps
-its single real device). Fits the measured times to the paper's model form
-t(M) = alpha + delta*M and reports the fit + MAPE.
+Runs in a subprocess with N virtual host devices, pinned to the CPU backend
+(``JAX_PLATFORMS=cpu``): the children never reach for an accelerator the
+parent may hold, and every number they print is a host-CPU number. Fits
+the measured times to the paper's model form t(M) = alpha + delta*M and
+reports the fit + MAPE.
 
 Prints CSV: devices,seq_put_us,mc_put_us,poll_wait_us,credit_wait_us
 """
@@ -61,7 +63,8 @@ def run_poll():
     o, c = step(xb); pl.wait(o)
 t_credit = best(run_credit)
 t_poll = best(run_poll)
-print(json.dumps(dict(devices=devs, seq_put_s=t_sq, mc_put_s=t_mc,
+print(json.dumps(dict(devices=devs, platform=jax.devices()[0].platform,
+                      seq_put_s=t_sq, mc_put_s=t_mc,
                       poll_s=t_poll, credit_s=t_credit)))
 """
 
@@ -69,6 +72,7 @@ print(json.dumps(dict(devices=devs, seq_put_s=t_sq, mc_put_s=t_mc,
 def measure(devices: int) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          env.get("PYTHONPATH", "")])
@@ -82,6 +86,7 @@ def measure(devices: int) -> dict:
 def main():
     from repro.core import runtime_model as rm
     rows = [measure(d) for d in (1, 2, 4, 8)]
+    print(f"# platform: {rows[0]['platform']} (host virtual devices)")
     print("devices,seq_put_us,mc_put_us,poll_wait_us,credit_wait_us")
     for r in rows:
         print(f"{r['devices']},{r['seq_put_s']*1e6:.0f},"

@@ -37,6 +37,8 @@ def main(argv=None) -> None:
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="write machine-readable records to PATH")
     args = ap.parse_args(argv)
+    from repro.runtime.flags import enable_compile_cache
+    enable_compile_cache()
 
     records: list[dict] = []
 
@@ -124,7 +126,7 @@ def main(argv=None) -> None:
     records += overload_ab.main(fast=args.fast, smoke=args.smoke)
 
     if not args.fast:
-        section("Measured dispatch/sync scaling on host devices (us)")
+        section("Measured dispatch/sync scaling on host CPU devices (us)")
         from benchmarks import dispatch_microbench
         dispatch_microbench.main()
 

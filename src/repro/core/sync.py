@@ -28,7 +28,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 class FaultDetected(RuntimeError):
@@ -36,8 +36,16 @@ class FaultDetected(RuntimeError):
 
 
 def _flat_spec(mesh: jax.sharding.Mesh) -> NamedSharding:
-    """One credit slot per device: a vector sharded over every mesh axis."""
-    return NamedSharding(mesh, P(mesh.axis_names))
+    """One credit slot per device: a vector sharded over every mesh axis.
+
+    The sharding lives on an ``Auto``-typed view of the mesh's devices, so
+    the constraint holds whatever axis types the caller's mesh has
+    (``jax.make_mesh`` makes ``Explicit`` axes, which a sharding
+    constraint may not name).
+    """
+    auto = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+    return NamedSharding(auto, P(mesh.axis_names))
 
 
 def credit_threshold(mesh: jax.sharding.Mesh) -> int:

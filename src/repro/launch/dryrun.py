@@ -44,28 +44,8 @@ _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s64": 8, "u64": 8,
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """compiled.cost_analysis() across jax versions.
-
-    Older jax returns a list with one properties-dict per executable;
-    newer jax returns the dict directly.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
-def peak_memory_bytes(ma) -> int:
-    """Per-device peak from memory_analysis(), across jax versions.
-
-    Older jaxlib CompiledMemoryStats has no ``peak_memory_in_bytes``; the
-    standard decomposition (arguments + outputs + temporaries - aliased)
-    upper-bounds the live set the missing field reports.
-    """
-    if hasattr(ma, "peak_memory_in_bytes"):
-        return int(ma.peak_memory_in_bytes)
-    return int(ma.argument_size_in_bytes + ma.output_size_in_bytes
-               + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    """compiled.cost_analysis(), or {} when the backend reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def _shape_bytes(type_str: str) -> int:
@@ -195,7 +175,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool,
 
     ma = compiled.memory_analysis()
     ca = cost_analysis_dict(compiled)
-    peak = peak_memory_bytes(ma)
+    peak = int(ma.peak_memory_in_bytes)
     hlo = compiled.as_text()
     colls = parse_collectives(hlo)
 

@@ -9,19 +9,14 @@ parallelism (gradient reduction crosses the pod interconnect once per step).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types; older releases default to Auto
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """jax.make_mesh across jax versions (Auto axis types where supported)."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with ``Auto`` axes: the partitioner places what the
+    sharding rules leave open (bare ``jax.make_mesh`` makes them
+    ``Explicit``)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -307,7 +307,10 @@ def serve_stream(args) -> dict:
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-8b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the scaled_down config (default); "
+                         "--no-reduced serves the published widths")
     # One-shot (legacy) driver.
     ap.add_argument("--one-shot", action="store_true",
                     help="original single-batch driver with one offline "
@@ -449,6 +452,8 @@ def main(argv=None):
                     help="dump the machine-readable metrics summary() dict "
                          "as JSON (single-fabric and fleet)")
     args = ap.parse_args(argv)
+    from repro.runtime.flags import enable_compile_cache
+    enable_compile_cache()
 
     if args.one_shot:
         out = serve(args.arch, reduced=args.reduced, prompts=args.prompts,

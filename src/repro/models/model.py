@@ -29,6 +29,21 @@ from .layers import (NO_SHARD, ShardCtx, attention_block, mamba_block,
 Params = dict[str, Any]
 
 
+def _normal(key, shape, div: float | None = None) -> jax.Array:
+    """Standard-normal float32 draws (over ``div``), rounded as eager code.
+
+    Under ``jit`` XLA would fuse the sampler's arithmetic with the scale
+    that follows, and turn a division by a constant into a product with its
+    reciprocal; both round differently.  Behind the barrier, jitted and
+    eager :func:`init_params` draw bit-identical weights.
+    """
+    x = jax.random.normal(key, shape)
+    if div is None:
+        return jax.lax.optimization_barrier(x)
+    x, div = jax.lax.optimization_barrier((x, jnp.float32(div)))
+    return x / div
+
+
 # --------------------------------------------------------------------------- #
 # Init
 # --------------------------------------------------------------------------- #
@@ -39,10 +54,10 @@ def _init_attn(key, cfg: ModelConfig, dt):
     s = 1.0 / math.sqrt(d)
     so = 1.0 / math.sqrt(cfg.num_heads * hd)
     return {
-        "wq": (jax.random.normal(ks[0], (d, cfg.num_heads * hd)) * s).astype(dt),
-        "wk": (jax.random.normal(ks[1], (d, cfg.num_kv_heads * hd)) * s).astype(dt),
-        "wv": (jax.random.normal(ks[2], (d, cfg.num_kv_heads * hd)) * s).astype(dt),
-        "wo": (jax.random.normal(ks[3], (cfg.num_heads * hd, d)) * so).astype(dt),
+        "wq": (_normal(ks[0], (d, cfg.num_heads * hd)) * s).astype(dt),
+        "wk": (_normal(ks[1], (d, cfg.num_kv_heads * hd)) * s).astype(dt),
+        "wv": (_normal(ks[2], (d, cfg.num_kv_heads * hd)) * s).astype(dt),
+        "wo": (_normal(ks[3], (cfg.num_heads * hd, d)) * so).astype(dt),
     }
 
 
@@ -51,11 +66,11 @@ def _init_mlp(key, cfg: ModelConfig, dt):
     ks = jax.random.split(key, 3)
     s_in, s_out = 1.0 / math.sqrt(d), 1.0 / math.sqrt(f)
     p = {
-        "w_in": (jax.random.normal(ks[0], (d, f)) * s_in).astype(dt),
-        "w_out": (jax.random.normal(ks[1], (f, d)) * s_out).astype(dt),
+        "w_in": (_normal(ks[0], (d, f)) * s_in).astype(dt),
+        "w_out": (_normal(ks[1], (f, d)) * s_out).astype(dt),
     }
     if cfg.gated_mlp:
-        p["w_gate"] = (jax.random.normal(ks[2], (d, f)) * s_in).astype(dt)
+        p["w_gate"] = (_normal(ks[2], (d, f)) * s_in).astype(dt)
     return p
 
 
@@ -64,10 +79,10 @@ def _init_moe(key, cfg: ModelConfig, dt):
     ks = jax.random.split(key, 4)
     s_in, s_out = 1.0 / math.sqrt(d), 1.0 / math.sqrt(f)
     return {
-        "w_router": (jax.random.normal(ks[0], (d, e)) * s_in).astype(jnp.float32),
-        "w_gate": (jax.random.normal(ks[1], (e, d, f)) * s_in).astype(dt),
-        "w_in": (jax.random.normal(ks[2], (e, d, f)) * s_in).astype(dt),
-        "w_out": (jax.random.normal(ks[3], (e, f, d)) * s_out).astype(dt),
+        "w_router": (_normal(ks[0], (d, e)) * s_in).astype(jnp.float32),
+        "w_gate": (_normal(ks[1], (e, d, f)) * s_in).astype(dt),
+        "w_in": (_normal(ks[2], (e, d, f)) * s_in).astype(dt),
+        "w_out": (_normal(ks[3], (e, f, d)) * s_out).astype(dt),
     }
 
 
@@ -78,18 +93,17 @@ def _init_mamba(key, cfg: ModelConfig, dt):
     ks = jax.random.split(key, 8)
     s = 1.0 / math.sqrt(d)
     return {
-        "w_z": (jax.random.normal(ks[3], (d, di)) * s).astype(dt),
-        "w_x": (jax.random.normal(ks[4], (d, di)) * s).astype(dt),
-        "w_bc": (jax.random.normal(ks[5], (d, 2 * n)) * s).astype(dt),
-        "w_dt": (jax.random.normal(ks[6], (d, h)) * s).astype(dt),
-        "w_conv": (jax.random.normal(ks[1], (cfg.conv_width, conv_ch))
-                   / math.sqrt(cfg.conv_width)).astype(dt),
+        "w_z": (_normal(ks[3], (d, di)) * s).astype(dt),
+        "w_x": (_normal(ks[4], (d, di)) * s).astype(dt),
+        "w_bc": (_normal(ks[5], (d, 2 * n)) * s).astype(dt),
+        "w_dt": (_normal(ks[6], (d, h)) * s).astype(dt),
+        "w_conv": _normal(ks[1], (cfg.conv_width, conv_ch),
+                          div=math.sqrt(cfg.conv_width)).astype(dt),
         "a_log": jnp.zeros((h,), jnp.float32),
         "dt_bias": jnp.full((h,), -2.0, jnp.float32),  # softplus ~= 0.12
         "d_skip": jnp.ones((h,), jnp.float32),
         "w_norm": jnp.zeros((di,), jnp.float32),
-        "w_out": (jax.random.normal(ks[2], (di, d))
-                  / math.sqrt(di)).astype(dt),
+        "w_out": _normal(ks[2], (di, d), div=math.sqrt(di)).astype(dt),
     }
 
 
@@ -120,7 +134,8 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> Params:
     def stack_init(k, kind):
         def one(kk):
             return _init_block(kk, kind, cfg, dt)
-        return jax.vmap(one)(jax.random.split(k, cfg.full_groups))
+        # One layer at a time: the float32 draws never exceed one layer.
+        return jax.lax.map(one, jax.random.split(k, cfg.full_groups))
 
     groups = tuple(
         stack_init(keys[i], kind) for i, kind in enumerate(cfg.pattern))
@@ -129,7 +144,7 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> Params:
         for i, kind in enumerate(cfg.tail))
     vp = cfg.vocab_padded
     params: Params = {
-        "embed": (jax.random.normal(keys[-1], (vp, d)) * 0.02).astype(dt),
+        "embed": (_normal(keys[-1], (vp, d)) * 0.02).astype(dt),
         "groups": groups,
         "tail": tail,
         "final_norm": jnp.zeros((d,), jnp.float32),
@@ -137,8 +152,8 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> Params:
     if cfg.uses_shared_block:
         params["shared"] = _init_block(keys[-2], "attn", cfg, dt)
     if not cfg.tie_embeddings:
-        params["lm_head"] = (jax.random.normal(keys[-3], (d, vp))
-                             / math.sqrt(d)).astype(dt)
+        params["lm_head"] = _normal(keys[-3], (d, vp),
+                                    div=math.sqrt(d)).astype(dt)
     return params
 
 

@@ -51,6 +51,7 @@ tested in tests/test_serve.py).
 from __future__ import annotations
 
 import dataclasses
+import time
 import warnings
 
 from repro.runtime.fault import FaultEvent, FaultInjector
@@ -245,6 +246,10 @@ def serve_workload(
     ``priority`` orders the arrived backlog by tenant class, ``preempt``
     evicts running lower classes for premium arrivals, and ``shed_depth``
     rejects over-backlog classes at admission — all default-off.
+
+    Besides the batcher's results, the returned dict carries
+    ``engine_setup_s``: the seconds spent building and warming the engine,
+    compilation included (0 without ``execute``).
     """
     cfg = _config_from_kwargs(config, ServeConfig, kwargs, "serve_workload")
     spec = spec or WorkloadSpec()
@@ -322,7 +327,9 @@ def serve_workload(
     requests = spec.build(with_tokens=cfg.execute)
 
     engine = None
+    setup_s = 0.0
     if cfg.execute:
+        t_setup = time.perf_counter()
         # Size the decode cache from the *generated* trace, not the spec's
         # nominal length mix: multi-turn sessions carry cumulative context
         # (DESIGN.md §13.1), so a later turn's prompt can exceed
@@ -340,6 +347,7 @@ def serve_workload(
             # lengths actually present.
             engine.warmup(sorted({r.prompt_len for r in requests}),
                           slots=not cfg.wave_boundary)
+        setup_s = time.perf_counter() - t_setup
     faults = cfg.faults
     if isinstance(faults, str):
         horizon = max((r.arrival for r in requests), default=0.0)
@@ -365,6 +373,7 @@ def serve_workload(
         out["requests"] = sorted(out["requests"] + out["orphans"],
                                  key=lambda r: r.rid)
     out["arch"] = cfg.arch
+    out["engine_setup_s"] = setup_s
     out["spec"] = spec
     out["faults"] = faults
     out["config"] = cfg

@@ -113,7 +113,6 @@ class ServingEngine:
         self._init_cache = init_cache
 
         with self.mesh:
-            self.params = init_params(jax.random.key(param_seed), cfg)
             caches_abs = jax.eval_shape(
                 lambda: init_cache(cfg, max_batch, max_len=max_len))
             c_spec = cache_specs(caches_abs, cfg, self.mesh)
@@ -131,13 +130,14 @@ class ServingEngine:
                 dec.fn, in_shardings=dec.in_shardings,
                 out_shardings=dec.out_shardings,
                 donate_argnums=dec.donate_argnums)
+            # Parameters are drawn on the devices, in their own dtype and
+            # sharding: no float32 copy, and each device makes only its
+            # shard.  Same values as eager ``init_params`` for one seed.
+            self.params = jax.jit(
+                lambda key: init_params(key, cfg),
+                out_shardings=dec.in_shardings[0])(
+                    jax.random.key(param_seed))
             self._tok_sharding = None
-            self._params_placed = False
-
-    def _place_params(self, shardings) -> None:
-        if not self._params_placed:
-            self.params = self._jax.device_put(self.params, shardings)
-            self._params_placed = True
 
     def _get_prefill(self, prompt_len: int):
         if prompt_len not in self._prefill_jit:
@@ -147,7 +147,6 @@ class ServingEngine:
                 (self.max_batch, prompt_len), jnp.int32)}
             pre = make_prefill_step(self.cfg, self.mesh, batch_abs,
                                     max_len=self.max_len)
-            self._place_params(pre.in_shardings[0])
             self._tok_sharding = pre.in_shardings[1]["tokens"]
             self._prefill_jit[prompt_len] = jax.jit(
                 pre.fn, in_shardings=pre.in_shardings,
@@ -162,7 +161,6 @@ class ServingEngine:
                 (self.max_batch, prompt_len), jnp.int32)}
             pre = make_slot_prefill_step(self.cfg, self.mesh, batch_abs,
                                          max_len=self.max_len)
-            self._place_params(pre.in_shardings[0])
             self._tok_sharding = pre.in_shardings[1]["tokens"]
             self._slot_prefill_jit[prompt_len] = jax.jit(
                 pre.fn, in_shardings=pre.in_shardings,
@@ -240,7 +238,6 @@ class ServingEngine:
         keep rejecting refits).  ``slots=True`` warms the prefill-into-slot
         path (continuous batching) instead of the wave prefill.
         """
-        from repro.core.sync import FaultDetected
         for length in sorted(set(prompt_lens)):
             tokens = np.zeros((self.max_batch, length), np.int32)
             if slots:
@@ -251,10 +248,7 @@ class ServingEngine:
             else:
                 _, caches, _ = self.prefill(tokens)
             tok = np.zeros((self.max_batch, 1), np.int32)
-            try:
-                self.decode(tok, caches, length)
-            except FaultDetected:  # pragma: no cover - warmup is best-effort
-                pass
+            self.decode(tok, caches, length)
 
     def decode_async(self, tok: np.ndarray, caches, lens) -> PendingStep:
         """Dispatch one decode step without blocking on its completion."""

@@ -90,8 +90,7 @@ with mesh:
                        donate_argnums=bundle.donate_argnums
                        ).lower(*bundle.abstract_args).compile()
 ma = compiled.memory_analysis()
-from repro.launch.dryrun import peak_memory_bytes
-assert peak_memory_bytes(ma) > 0
+assert ma.peak_memory_in_bytes > 0
 colls = parse_collectives(compiled.as_text())
 kinds = set(colls["per_device_bytes_by_kind"])
 assert colls["per_device_bytes_total"] > 0
@@ -124,7 +123,6 @@ with mesh:
                        out_shardings=bundle.out_shardings,
                        donate_argnums=bundle.donate_argnums
                        ).lower(*bundle.abstract_args).compile()
-from repro.launch.dryrun import peak_memory_bytes
-print("OK", peak_memory_bytes(compiled.memory_analysis()) > 0)
+print("OK", compiled.memory_analysis().peak_memory_in_bytes > 0)
 """, devices=8)
     assert r.returncode == 0 and "OK True" in r.stdout, r.stderr[-3000:]

@@ -351,3 +351,18 @@ def test_merge_cache_slots_selects_rows_by_mask():
     assert (g[:, 0] == 1).all() and (g[:, 2] == 1).all()
     assert (g[:, 1] == 0).all()
     assert (t[0] == 1).all() and (t[2] == 1).all() and (t[1] == 0).all()
+
+
+@pytest.mark.parametrize("arch", ["chatglm3-6b", "mamba2-370m",
+                                  "qwen3-moe-30b-a3b", "zamba2-1.2b"])
+def test_jitted_init_matches_eager_init(arch):
+    """init_params under jit (how the serving engine draws its weights)
+    gives bit-identical values to the eager call: dense, SSM, MoE and
+    hybrid blocks, including the scales that divide."""
+    from repro.configs import get_config
+    from repro.models import scaled_down
+    cfg = scaled_down(get_config(arch))
+    eager = init_params(jax.random.key(0), cfg)
+    jitted = jax.jit(lambda k: init_params(k, cfg))(jax.random.key(0))
+    for a, b in zip(jax.tree.leaves(eager), jax.tree.leaves(jitted)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

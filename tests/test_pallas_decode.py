@@ -2,13 +2,13 @@
 
 Numerics contract (docs/kernels.md):
 
-  * **Single-chunk shapes** (``pick_chunk(slots) == slots``): the fused
-    kernel is *bit-exact* against the unfused path — the qk scores, the
-    one-shot softmax, and the one-einsum p@v see identical inputs in
-    identical order, and interpret mode runs the same XLA ops.  The six
-    parametrized cases below (plain / bf16 / quant / ring / window /
-    quant+ring, all with mixed per-slot lengths) assert exact equality.
-  * **Multi-chunk shapes**: the fused and unfused paths are two separately
+  * **Small shapes** (the six parametrized cases below, up to 64 slots):
+    the fused kernel is *bit-exact* against the unfused path — the qk
+    scores, the one-shot softmax, and the one-einsum p@v see identical
+    inputs in identical order, and interpret mode runs the same XLA ops.
+    The cases (plain / bf16 / quant / ring / window / quant+ring, all with
+    mixed per-slot lengths) assert exact equality.
+  * **Larger shapes**: the fused and unfused paths are two separately
     compiled XLA graphs, and XLA:CPU may contract FMAs / tile reductions
     differently per graph — so the contract is: v-cache bit-exact (pure
     copy, no arithmetic), k-cache and attention out within a few f32 ULP
@@ -36,7 +36,7 @@ except ImportError:  # no hypothesis: seeded-sampling shim, not a skip
     from proptest_fallback import given, settings, strategies as st
 
 from repro.configs import get_config
-from repro.kernels.decode_attention import fused_decode_attention, pick_chunk
+from repro.kernels.decode_attention import fused_decode_attention
 from repro.models.config import ModelConfig
 from repro.models.layers import (NEG_INF, NO_SHARD, apply_rope,
                                  attention_block, chunked_attention,
@@ -114,7 +114,7 @@ def _make_case(cfg, b, s, h, kh, d, dtype, lens, quant, is_ring, window,
 
 
 # (name, cfg, B, S, H, K, D, dtype, lens, quant, is_ring, window) — all
-# single-chunk shapes (pick_chunk(S) == S), where exact equality holds.
+# small shapes (S <= 64), where exact equality holds.
 EXACT_CASES = [
     ("plain-half-rope", CFG_HALF, 3, 64, 8, 2, 16, jnp.float32,
      [5, 0, 63], False, False, 0),
@@ -135,10 +135,9 @@ EXACT_CASES = [
     "case", EXACT_CASES, ids=[c[0] for c in EXACT_CASES])
 def test_fused_matches_unfused_bitwise_single_chunk(case):
     """Plain / quant / ring / window variants, mixed per-slot lens: every
-    output (attention out, caches, scales) is bit-identical on shapes
-    where the score pass is one chunk (see module docstring)."""
+    output (attention out, caches, scales) is bit-identical on these
+    small shapes (see module docstring)."""
     name, cfg, b, s, h, kh, d, dtype, lens, quant, is_ring, window = case
-    assert pick_chunk(s) == s or s <= 64  # single-chunk precondition
     pairs = _make_case(cfg, b, s, h, kh, d, dtype, lens, quant, is_ring,
                        window)
     for nm, (got, ref) in pairs.items():
@@ -151,7 +150,7 @@ def test_fused_matches_unfused_bitwise_single_chunk(case):
 
 
 def test_fused_matches_unfused_multi_chunk_ulp():
-    """S=128 (two 64-wide score chunks): v-cache bit-exact, k-cache and
+    """S=128, head_dim 32: v-cache bit-exact, k-cache and
     out within the documented ULP tolerance (separately compiled graphs
     may contract FMAs differently — docs/kernels.md)."""
     pairs = _make_case(CFG_HALF, 4, 128, 8, 2, 32, jnp.float32,
@@ -173,7 +172,7 @@ def test_fused_property_random_shapes(b, g, d, seed):
     """Random (batch, GQA group, head_dim, lens) within the ULP contract."""
     kh = 2
     h = kh * g
-    s = 128  # multi-chunk
+    s = 128
     rng = np.random.default_rng(seed)
     lens = rng.integers(0, s, size=b).tolist()
     pairs = _make_case(CFG_STD, b, s, h, kh, d, jnp.float32, lens,
@@ -292,7 +291,7 @@ def _block_case(*, quant, window, slots, h=4, kh=2, d=16, b=3):
 ], ids=["plain", "ring", "quant"])
 def test_attention_block_fused_flag_equivalence(quant, window, slots):
     """attention_block(fused=True) reproduces the unfused decode branch end
-    to end (projections included) on single-chunk shapes."""
+    to end (projections included) on small shapes."""
     (y_ref, c_ref), (y_got, c_got) = _block_case(quant=quant, window=window,
                                                  slots=slots)
     np.testing.assert_allclose(np.asarray(y_got), np.asarray(y_ref),
@@ -313,8 +312,17 @@ def test_attention_block_fused_flag_equivalence(quant, window, slots):
                                        err_msg=nm)
 
 
-def test_pick_chunk_divides_and_prefers_large():
-    assert pick_chunk(512) == 64
-    assert pick_chunk(64) == 64
-    assert pick_chunk(48) == 16
-    assert pick_chunk(7) == 1
+def test_fused_decode_refuses_a_cache_row_past_vmem():
+    """A row's whole cache is one VMEM block (ROADMAP A6): past the budget
+    the wrapper names the limit instead of handing Mosaic a kernel that
+    cannot fit."""
+    from repro.kernels.decode_attention import VMEM_LIMIT_BYTES, vmem_bytes
+    b, s, h, kh, d = 1, 8192, 32, 2, 128
+    assert vmem_bytes(h, kh, d, s, jnp.bfloat16) > VMEM_LIMIT_BYTES
+    sds = jax.ShapeDtypeStruct
+    args = (sds((b, 1, h, d), jnp.bfloat16), sds((b, 1, kh, d), jnp.bfloat16),
+            sds((b, 1, kh, d), jnp.bfloat16), sds((b, s, kh, d), jnp.bfloat16),
+            sds((b, s, kh, d), jnp.bfloat16), sds((b,), jnp.int32),
+            sds((b, 1, d // 4), jnp.float32), sds((b, 1, d // 4), jnp.float32))
+    with pytest.raises(ValueError, match="VMEM_LIMIT_BYTES"):
+        jax.eval_shape(lambda *a: fused_decode_attention(*a), *args)

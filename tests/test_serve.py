@@ -544,3 +544,31 @@ def test_continuous_mixed_length_slots_match_wave_boundary_tokens():
     for rw, rc in zip(wave["requests"], cont["requests"]):
         assert rw.rid == rc.rid
         np.testing.assert_array_equal(rw.generated, rc.generated)
+
+
+@pytest.mark.parametrize("argv,reduced", [
+    ([], True), (["--reduced"], True), (["--no-reduced"], False)],
+    ids=["default", "reduced", "no-reduced"])
+def test_cli_reduced_flag_reaches_serve_config(argv, reduced):
+    """``--no-reduced`` is how the CLI reaches the published widths."""
+    from repro.launch.serve import main
+    out = main(["--no-execute", "--requests", "4", "--arch", "chatglm3-6b",
+                *argv])
+    assert out["config"].reduced is reduced
+
+
+def test_engine_params_are_eager_init_params_in_place():
+    """The engine draws its weights under jit, straight into the decode
+    step's parameter shardings, with the values eager init_params gives."""
+    import jax
+    from repro.configs import get_config
+    from repro.models import init_params, scaled_down
+    from repro.serve import ServingEngine
+    eng = ServingEngine("chatglm3-6b", reduced=True, max_batch=2, max_len=16,
+                        param_seed=3)
+    want = init_params(jax.random.key(3), scaled_down(get_config("chatglm3-6b")))
+    got_leaves, want_leaves = jax.tree.leaves(eng.params), jax.tree.leaves(want)
+    assert len(got_leaves) == len(want_leaves)
+    for got, ref in zip(got_leaves, want_leaves):
+        assert got.dtype == ref.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
