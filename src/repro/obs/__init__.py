@@ -13,7 +13,10 @@
 
 Instrumented layers: ``core.engine`` (per-job dispatch/exec/sync phase
 spans, host vs fabric tracks), ``serve.batcher`` (request lifecycle, job
-spans, occupancy counters), ``serve.scheduler`` (plan/admission instants),
+spans, occupancy counters, and on the real clock the serving loop's and the
+engine's ``decode.*``/``prefill.*`` spans and set-up's ``setup.warmup``,
+each also a ``jax.profiler`` host annotation), ``serve.scheduler``
+(plan/admission instants),
 ``serve.calibrator`` (refit events with before/after coefficients), and
 ``serve.fleet`` (route decisions with per-lane scores + Eq.-3 verdicts,
 flow-linked to the execution they caused).  Capture with
@@ -24,9 +27,11 @@ flow-linked to the execution they caused).  Capture with
 from .export import (read_jsonl, to_chrome, write_chrome_trace,  # noqa: F401
                      write_jsonl)
 from .residual import Residual, ResidualTracker  # noqa: F401
-from .tracer import NULL, NullTracer, TraceEvent, Tracer  # noqa: F401
+from .tracer import (NULL, NULL_LANE, NullTracer, TraceEvent,  # noqa: F401
+                     Tracer, WallLane)
 
 __all__ = [
-    "NULL", "NullTracer", "Residual", "ResidualTracker", "TraceEvent",
-    "Tracer", "read_jsonl", "to_chrome", "write_chrome_trace", "write_jsonl",
+    "NULL", "NULL_LANE", "NullTracer", "Residual", "ResidualTracker",
+    "TraceEvent", "Tracer", "WallLane", "read_jsonl", "to_chrome",
+    "write_chrome_trace", "write_jsonl",
 ]

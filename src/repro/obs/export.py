@@ -8,11 +8,13 @@ Perfetto uses for labels, and converts both time domains to the format's
 microsecond axis:
 
   * ``cycles`` at the paper's 1 GHz clock: 1 cycle == 1 ns == 1e-3 us;
-  * ``wall_s`` measured host seconds: 1 s == 1e6 us.
+  * ``wall_s`` host ``perf_counter`` seconds: 1 s == 1e6 us, shifted so
+    that the first wall-domain event of the trace is at 0.
 
-The two domains share **no epoch**, so wall-domain procs are exported as
-separate ``wall:<proc>`` processes — side by side, never overlaid
-(DESIGN.md §9).
+The virtual cycle clock shares **no epoch** with the host's, so wall-domain
+procs are exported as separate ``wall:<proc>`` processes — side by side,
+never overlaid (DESIGN.md §9).  All of them share the one shift, so wall
+spans of different procs stay aligned with each other.
 
 The JSONL exporter writes one raw event dict per line (recording order,
 native time units) — the machine-readable log ``tools/trace_report.py`` and
@@ -42,8 +44,9 @@ def _proc_key(e) -> str:
     return e.proc if e.domain == "cycles" else f"wall:{e.proc}"
 
 
-def _ts_us(e) -> float:
-    return e.ts / CYCLES_PER_US if e.domain == "cycles" else e.ts * 1e6
+def _ts_us(e, wall0: float) -> float:
+    return (e.ts / CYCLES_PER_US if e.domain == "cycles"
+            else (e.ts - wall0) * 1e6)
 
 
 def to_chrome(tracer: Tracer) -> dict:
@@ -51,6 +54,8 @@ def to_chrome(tracer: Tracer) -> dict:
     pids: dict[str, int] = {}
     tids: dict[tuple[str, str], int] = {}
     out: list[dict] = []
+    wall0 = min((e.ts for e in tracer.events if e.domain == "wall_s"),
+                default=0.0)
 
     for e in tracer.events:
         proc = _proc_key(e)
@@ -64,7 +69,7 @@ def to_chrome(tracer: Tracer) -> dict:
             out.append({"ph": "M", "name": "thread_name", "pid": pids[proc],
                         "tid": tids[key], "args": {"name": e.track}})
         rec = {"ph": e.ph, "name": e.name, "cat": e.track,
-               "pid": pids[proc], "tid": tids[key], "ts": _ts_us(e)}
+               "pid": pids[proc], "tid": tids[key], "ts": _ts_us(e, wall0)}
         if e.ph == "X":
             rec["dur"] = e.dur / CYCLES_PER_US if e.domain == "cycles" \
                 else e.dur * 1e6

@@ -31,20 +31,30 @@ id is the request id.
 
 Two time domains (DESIGN.md §9): ``domain="cycles"`` is the fabric-cycle
 virtual clock the scheduler plans in (at the paper's 1 GHz, cycles == ns);
-``domain="wall_s"`` is measured host seconds from the real JAX engine steps.
-The exporter keeps the domains in separate process groups — they share no
-epoch, so they must never be rendered on one axis as if aligned.
+``domain="wall_s"`` is the host's ``time.perf_counter()`` in seconds, for
+the served path on a real engine.  :meth:`Tracer.wall` records such a span
+around a block of host code, and a :class:`WallLane` records consecutive
+ones (a serving loop's layers): each has a real start, a real end, and,
+while it is open, a ``jax.profiler.TraceAnnotation`` of the same name, so
+that under a profiler session every wall span is also a host event of the
+device trace, on the profiler's own clock.  The exporter keeps the domains
+in separate process groups: the cycle clock shares no epoch with the
+host's.
 
 Overhead budget: tracing defaults to **off** — every instrumentation site
 guards with ``if tracer is not None`` (or holds the shared :data:`NULL`
-no-op whose methods return immediately), so the disabled cost is one
-attribute check per event site and the benchmark headlines stay inside the
+no-op whose methods return immediately, and whose lane is a shared no-op
+too), so the disabled cost is one attribute check or one no-op call per
+event site and the benchmark headlines stay inside the
 ``tools/bench_compare.py`` gate.  Enabled cost is one dataclass append per
-event; exporters do all formatting after the run.
+event (plus a clock read and a profiler annotation per wall span);
+exporters do all formatting after the run.
 """
 
 from __future__ import annotations
 
+import contextlib
+import time
 from dataclasses import dataclass
 
 #: The tracer's two time domains (DESIGN.md §9).
@@ -121,6 +131,29 @@ class Tracer:
         self.events.append(TraceEvent("f", name, proc, track, ts, 0.0,
                                       domain, None, flow))
 
+    def annotation(self, name: str):
+        """A ``jax.profiler.TraceAnnotation``: while it is open, a running
+        profiler session records a host event ``name`` on its own clock."""
+        from jax.profiler import TraceAnnotation
+        return TraceAnnotation(name)
+
+    def lane(self, proc: str) -> "WallLane":
+        """A :class:`WallLane`: consecutive ``wall_s`` spans on ``proc``."""
+        return WallLane(self, proc)
+
+    @contextlib.contextmanager
+    def wall(self, proc: str, track: str, name: str,
+             args: dict | None = None):
+        """Context manager: the block it wraps as one ``wall_s`` span,
+        from ``time.perf_counter()`` on entry to the same on exit, with the
+        block inside :meth:`annotation` of the same name."""
+        lane = WallLane(self, proc)
+        lane.mark(track, name, args)
+        try:
+            yield
+        finally:
+            lane.close()
+
     # ------------------------------------------------------------------ #
     def lane_events(self, proc: str) -> list[tuple]:
         """Comparable event tuples of one proc, flow linkage excluded.
@@ -142,6 +175,65 @@ class Tracer:
         for e in self.events:
             seen.setdefault(e.proc)
         return list(seen)
+
+
+class WallLane:
+    """Consecutive ``wall_s`` spans on one proc, such as a serving loop's
+    layers.
+
+    :meth:`mark` ends the open span and starts the next at one
+    ``time.perf_counter()`` instant, so the spans tile the code between
+    marks with no time left out; :meth:`close` ends the open span.  While a
+    span is open, its :meth:`Tracer.annotation` is open too.  ``args`` is
+    stored by reference: the code under a span may still fill it in.
+    """
+
+    __slots__ = ("tracer", "proc", "_open")
+
+    def __init__(self, tracer: Tracer, proc: str):
+        self.tracer, self.proc = tracer, proc
+        self._open = None      # (track, name, args, start, annotation)
+
+    def mark(self, track: str, name: str, args: dict | None = None) -> None:
+        t = time.perf_counter()
+        self._end(t)
+        ann = self.tracer.annotation(name)
+        ann.__enter__()
+        self._open = (track, name, args, t, ann)
+
+    def close(self) -> None:
+        self._end(time.perf_counter())
+
+    def _end(self, t: float) -> None:
+        if self._open is None:
+            return
+        track, name, args, t0, ann = self._open
+        self._open = None
+        ann.__exit__(None, None, None)
+        self.tracer.events.append(TraceEvent("X", name, self.proc, track, t0,
+                                             t - t0, "wall_s", args))
+
+
+class _NullLane:
+    """The lane of :class:`NullTracer`: records nothing."""
+
+    __slots__ = ()
+
+    def __bool__(self) -> bool:
+        return False
+
+    def mark(self, *a, **k) -> None:
+        pass
+
+    def close(self) -> None:
+        pass
+
+
+#: The one no-op lane (stateless, shared).
+NULL_LANE = _NullLane()
+
+#: The one no-op context :class:`NullTracer` hands out (reusable).
+_NULL_CONTEXT = contextlib.nullcontext()
 
 
 class NullTracer:
@@ -171,6 +263,12 @@ class NullTracer:
 
     def flow_end(self, *a, **k) -> None:
         pass
+
+    def lane(self, proc: str) -> _NullLane:
+        return NULL_LANE
+
+    def wall(self, *a, **k) -> contextlib.nullcontext:
+        return _NULL_CONTEXT
 
 
 #: Shared no-op instance — components store this when no tracer is attached.

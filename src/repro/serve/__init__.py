@@ -238,7 +238,10 @@ def serve_workload(
     — and ``residuals`` (a :class:`repro.obs.ResidualTracker`) pairs every
     prediction with its measured outcome (DESIGN.md §9).  The trace process
     is named like a one-lane fleet's lane 0 (``f0:{clusters}c``), so a 1x32
-    fleet trace is event-identical to this path modulo routing.
+    fleet trace is event-identical to this path modulo routing.  With
+    ``fabric="wallclock"`` the tracer also records the engine's warm-up
+    (``setup.warmup``) and the continuous loop's layers on the host's
+    real clock (``decode.*``, ``prefill.*``).
 
     ``affinity=True`` attaches a :class:`PrefixStore` (DESIGN.md §13):
     admission resolves each session request's warm-hit length against the
@@ -339,7 +342,8 @@ def serve_workload(
         engine = ServingEngine(cfg.arch, reduced=cfg.reduced,
                                max_batch=cfg.max_batch, max_len=max_len,
                                mesh_shape=cfg.mesh_shape,
-                               fused_decode=cfg.fused_decode)
+                               fused_decode=cfg.fused_decode,
+                               tracer=cfg.tracer)
         if cfg.fabric == "wallclock":
             # Compile outliers must not enter the measured step times the
             # calibrator fits (see ServingEngine.warmup).  Session traces

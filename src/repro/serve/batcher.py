@@ -47,12 +47,15 @@ count.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import time
 
 import numpy as np
 
 from repro.core import simulator as sim
 from repro.kernels.ops import get_kernel
+from repro.obs import NULL, NULL_LANE
 
 from .calibrator import OnlineCalibrator
 from .fabric import SimulatedFabric, WallClockFabric
@@ -60,6 +63,9 @@ from .metrics import ServeMetrics
 from .prefix import PrefixStore
 from .queue import Request, RequestQueue, RequestState
 from .scheduler import BatchPlan, OffloadAwareScheduler
+
+#: The no-op context an untraced engine step runs in.
+_NO_SPAN = contextlib.nullcontext()
 
 
 @dataclasses.dataclass
@@ -75,6 +81,7 @@ class PendingStep:
 
     out: dict
     dispatch_s: float = 0.0        # measured operand-placement seconds
+    kind: str = "decode"           # "decode" | "prefill"
 
 
 class ServingEngine:
@@ -82,7 +89,7 @@ class ServingEngine:
 
     def __init__(self, arch: str, *, reduced: bool = True, max_batch: int = 4,
                  max_len: int = 64, mesh_shape=(1, 1), param_seed: int = 0,
-                 fused_decode: bool = False):
+                 fused_decode: bool = False, tracer=None):
         import jax
         import jax.numpy as jnp
         from repro.configs import get_config
@@ -111,6 +118,10 @@ class ServingEngine:
         self._prefill_jit: dict[int, object] = {}   # prompt_len -> jitted fn
         self._slot_prefill_jit: dict[int, object] = {}
         self._init_cache = init_cache
+        # repro.obs tracer of set-up's spans (``setup.warmup``).  The steps
+        # are traced only inside a serving loop's ``step_spans`` scope.
+        self.tracer = tracer if tracer is not None else NULL
+        self._spans = None    # the open step_spans scope, if any
 
         with self.mesh:
             caches_abs = jax.eval_shape(
@@ -173,6 +184,19 @@ class ServingEngine:
         with self.mesh:
             return self._initcache_jit()
 
+    def prefill_async(self, tokens: np.ndarray,
+                      metrics: ServeMetrics | None = None) -> PendingStep:
+        """Dispatch a wave prefill (fresh caches) without blocking on it."""
+        with self.mesh:
+            fn = self._get_prefill(tokens.shape[1])
+            # Multicast operand placement — one host call.
+            placed, dstats = self.dispatcher.timed_put(
+                tokens, self._tok_sharding)
+            if metrics is not None:
+                metrics.record_dispatch(dstats)
+            out = fn(self.params, {"tokens": placed})
+        return PendingStep(out=out, dispatch_s=dstats.seconds, kind="prefill")
+
     def prefill(self, tokens: np.ndarray,
                 metrics: ServeMetrics | None = None):
         """tokens (max_batch, L) int32 -> (next_token (B,), caches, wall_s).
@@ -183,17 +207,7 @@ class ServingEngine:
         compute + completion) — the measurement a WallClockFabric feeds to
         the online calibrator.
         """
-        with self.mesh:
-            fn = self._get_prefill(tokens.shape[1])
-            # Multicast operand placement — one host call.
-            placed, dstats = self.dispatcher.timed_put(
-                tokens, self._tok_sharding)
-            if metrics is not None:
-                metrics.record_dispatch(dstats)
-            out = fn(self.params, {"tokens": placed})
-            _, wait_s = self.sync.timed_wait(out["credits"])
-        return (np.asarray(out["next_token"]), out["caches"],
-                dstats.seconds + wait_s)
+        return self.wait_step(self.prefill_async(tokens, metrics))
 
     def prefill_into_slots_async(self, tokens: np.ndarray, caches,
                                  slot_mask: np.ndarray,
@@ -206,6 +220,7 @@ class ServingEngine:
         of the already-running slots) before calling :meth:`wait_step`.
         """
         jnp = self._jnp
+        self._mark("prefill.dispatch")
         with self.mesh:
             fn = self._get_slot_prefill(tokens.shape[1])
             placed, dstats = self.dispatcher.timed_put(
@@ -214,7 +229,7 @@ class ServingEngine:
                 metrics.record_dispatch(dstats)
             out = fn(self.params, {"tokens": placed}, caches,
                      jnp.asarray(slot_mask, bool))
-        return PendingStep(out=out, dispatch_s=dstats.seconds)
+        return PendingStep(out=out, dispatch_s=dstats.seconds, kind="prefill")
 
     def prefill_into_slots(self, tokens: np.ndarray, caches,
                            slot_mask: np.ndarray,
@@ -239,20 +254,27 @@ class ServingEngine:
         path (continuous batching) instead of the wave prefill.
         """
         for length in sorted(set(prompt_lens)):
-            tokens = np.zeros((self.max_batch, length), np.int32)
-            if slots:
-                caches = self.init_caches()
-                mask = np.zeros(self.max_batch, bool)
-                mask[0] = True
-                _, caches, _ = self.prefill_into_slots(tokens, caches, mask)
-            else:
-                _, caches, _ = self.prefill(tokens)
-            tok = np.zeros((self.max_batch, 1), np.int32)
-            self.decode(tok, caches, length)
+            # One ``setup.warmup`` span per length: its compiles (or
+            # compile-cache loads) and its first run.
+            with self.tracer.wall("engine", "setup", "setup.warmup",
+                                  {"prompt_len": length}
+                                  if self.tracer else None):
+                tokens = np.zeros((self.max_batch, length), np.int32)
+                if slots:
+                    caches = self.init_caches()
+                    mask = np.zeros(self.max_batch, bool)
+                    mask[0] = True
+                    _, caches, _ = self.prefill_into_slots(tokens, caches,
+                                                           mask)
+                else:
+                    _, caches, _ = self.prefill(tokens)
+                tok = np.zeros((self.max_batch, 1), np.int32)
+                self.decode(tok, caches, length)
 
     def decode_async(self, tok: np.ndarray, caches, lens) -> PendingStep:
         """Dispatch one decode step without blocking on its completion."""
         jnp = self._jnp
+        self._mark("decode.dispatch")
         lens = np.asarray(lens, np.int32)
         if lens.ndim == 0:
             lens = np.full((self.max_batch,), int(lens), np.int32)
@@ -276,6 +298,33 @@ class ServingEngine:
         is_ready = getattr(pending.out["credits"], "is_ready", None)
         return bool(is_ready()) if callable(is_ready) else False
 
+    @contextlib.contextmanager
+    def step_spans(self, tracer, proc: str, args: dict | None = None,
+                   lane=NULL_LANE):
+        """Record the steps run inside as ``wall_s`` spans of ``tracer`` on
+        ``proc``'s ``engine`` track, each carrying ``args``.
+
+        Each credit wait is a ``decode`` or ``prefill`` span: it starts on
+        ``time.perf_counter()`` when the wait does and lasts the step's
+        ``wall_s`` (so a prefill's also counts its operand put, which came
+        before the wait).  On a serving loop's ``lane`` (a
+        :class:`repro.obs.WallLane`) the rest of the step is marked too:
+        ``<kind>.dispatch`` from the call to the wait (operand put and
+        jitted call), and ``<kind>.pull`` from the wait on (the tokens'
+        copy to the host), which the loop's next mark ends.
+        """
+        self._spans = (tracer, proc, args, lane)
+        try:
+            yield
+        finally:
+            self._spans = None
+
+    def _mark(self, name: str) -> None:
+        """Start ``name`` on the lane of the open :meth:`step_spans`."""
+        if self._spans is not None:
+            _, _, args, lane = self._spans
+            lane.mark("engine", name, args)
+
     def wait_step(self, pending: PendingStep):
         """Block on a dispatched step; returns (next_token, caches, wall_s).
 
@@ -286,7 +335,18 @@ class ServingEngine:
         a WallClockFabric feeds the calibrator.
         """
         with self.mesh:
-            _, wait_s = self.sync.timed_wait(pending.out["credits"])
+            if self._spans is None:
+                _, wait_s = self.sync.timed_wait(pending.out["credits"])
+            else:
+                tracer, proc, args, lane = self._spans
+                lane.close()
+                with tracer.annotation(pending.kind):
+                    t0 = time.perf_counter()
+                    _, wait_s = self.sync.timed_wait(pending.out["credits"])
+                tracer.span(proc, "engine", pending.kind, t0,
+                            pending.dispatch_s + wait_s, domain="wall_s",
+                            args=args)
+                lane.mark("engine", f"{pending.kind}.pull", args)
         return (np.asarray(pending.out["next_token"]), pending.out["caches"],
                 pending.dispatch_s + wait_s)
 
@@ -355,6 +415,13 @@ class ContinuousBatcher:
         self.residuals = residuals
         self.proc = proc
         self.flow = flow
+        # Real-clock spans of the continuous loop's layers (``decode.plan``,
+        # ``prefill.admit``, ...) on one lane: only where the steps run on
+        # the real clock, so simulated-fabric and fleet traces stay as they
+        # were.
+        self._lane = (tracer.lane(proc) if tracer is not None
+                      and isinstance(self.fabric, WallClockFabric)
+                      else NULL_LANE)
         # Fault injection (DESIGN.md §10) — all optional, zero-cost when
         # unset.  ``faults`` is a runtime.fault.FaultInjector; ``fault_lane``
         # selects which of its lanes this batcher is.  The injector is
@@ -382,7 +449,6 @@ class ContinuousBatcher:
         self.orphans: list[Request] = []
         self._decode_count = 0
         self._ckpt_max_gen = 1
-        self._wall_t = 0.0   # wall-domain trace clock (real engine steps)
         self._energy_ts = 0.0  # monotonic clamp for the energy counter track
         # With a real engine attached, at most one decode may overlap an
         # in-flight prefill: the prefill is chained on that decode's cache
@@ -734,19 +800,13 @@ class ContinuousBatcher:
             self.tracer.counter(self.proc, "slots", "slots_occupied", ts,
                                 occupied)
 
-    def _record_wall(self, wall_s: float, name: str) -> None:
-        """One measured real-engine step: metrics + a wall-domain span.
-
-        Wall seconds share no epoch with the virtual cycle clock, so these
-        spans live on their own time axis (the exporter renders them as a
-        separate ``wall:`` process, DESIGN.md §9): consecutive measured
-        steps laid end to end.
-        """
-        self.metrics.step_wall_s.add(wall_s)
-        if self.tracer is not None:
-            self.tracer.span(self.proc, "engine", name, self._wall_t, wall_s,
-                             domain="wall_s", args={"wall_s": wall_s})
-        self._wall_t += wall_s
+    def _steps(self, args: dict | None = None, lane=NULL_LANE):
+        """The engine's ``step_spans`` scope for one step when traced (its
+        ``decode``/``prefill`` wait span, and its dispatch and pull on
+        ``lane``), a no-op context untraced."""
+        if self.tracer is None:
+            return _NO_SPAN
+        return self.engine.step_spans(self.tracer, self.proc, args, lane)
 
     # ------------------------------------------------------------------ #
     # Fault injection (DESIGN.md §10).  All hooks early-return when no
@@ -901,6 +961,7 @@ class ContinuousBatcher:
             clock = self._run_pipelined(queue, clock)
         else:
             clock = self._run_continuous(queue, clock)
+            self._lane.close()
 
         m.t_end = clock
         return {
@@ -917,8 +978,17 @@ class ContinuousBatcher:
     # Continuous (slot-managed) serving loop — DESIGN.md §6
     # ------------------------------------------------------------------ #
     def _run_continuous(self, queue: RequestQueue, clock: float) -> float:
+        """The slot-managed loop.  On the real clock (a WallClockFabric)
+        its lane marks each layer boundary of an iteration: ``decode.plan``,
+        the engine's ``decode.dispatch``, ``decode`` wait and
+        ``decode.pull``, then ``decode.account`` and ``decode.retire`` for a
+        decode step, and ``prefill.*`` for an admission
+        (:meth:`_prefill_slots`).  Each span lasts until the next mark, so
+        the loop's return to its top counts to the span before it.  No span
+        encloses a whole iteration."""
         m = self.metrics
         nb = self.max_batch
+        lane = self._lane
         slots: list[Request | None] = [None] * nb
         emitted = [0] * nb                     # tokens produced per slot
         gen_buf: list[list[int]] = [[] for _ in range(nb)]
@@ -945,20 +1015,11 @@ class ContinuousBatcher:
                     self._preempt_slot(i, slots, emitted, gen_buf, queue,
                                        clock)
                     free = [i]
-            occ_before = len(occupied())
             if free and queue.arrived(clock):
-                batch = self._form_wave(queue, clock, limit=len(free))
-                if batch:
-                    m.waves += 1
-                    if occ_before:
-                        m.mid_wave_admissions += len(batch)
-                    clock, caches = self._prefill_slots(
-                        batch, free[:len(batch)], slots, emitted, gen_buf,
-                        lens, tok, clock, caches)
-                    for i in free[:len(batch)]:
-                        if slots[i] is not None and \
-                                emitted[i] >= slots[i].gen_len:
-                            finish(i, clock)
+                clock, caches, admitted = self._prefill_slots(
+                    queue, free, slots, emitted, gen_buf, lens, tok, clock,
+                    caches)
+                if admitted:
                     continue   # re-check arrivals before the next decode
             occ = occupied()
             if not occ:
@@ -971,12 +1032,17 @@ class ContinuousBatcher:
                 continue
 
             # One decode step over every occupied slot (per-slot lengths).
+            args = {"rows": len(occ)} if lane else None
+            lane.mark("loop", "decode.plan", args)
             plan = self.scheduler.plan(len(occ), deadline=None, kind="decode",
                                        now=clock)
             wall = None
             if self.engine is not None:
-                next_tok, caches, wall = self.engine.decode(tok, caches, lens)
-                self._record_wall(wall, "decode")
+                with self._steps(args, lane):
+                    next_tok, caches, wall = self.engine.decode(tok, caches,
+                                                                lens)
+                m.step_wall_s.add(wall)
+            lane.mark("loop", "decode.account", args)
             t_dec = self._job_runtime(plan, wall)
             self._account_job(plan, t_dec, self._executed_n(plan, None),
                               now=clock + t_dec)
@@ -984,6 +1050,7 @@ class ContinuousBatcher:
             self._trace_job(plan, clock, t_dec)
             self._trace_occupancy(clock, len(occ))
             clock += t_dec
+            lane.mark("loop", "decode.retire", args)
             for i in occ:
                 lens[i] += 1
                 emitted[i] += 1
@@ -1081,31 +1148,54 @@ class ContinuousBatcher:
                 tok[slot, 0] = next_tok[slot]
                 gen_buf[slot].append(int(next_tok[slot]))
 
-    def _prefill_slots(self, batch: list[Request], take: list[int],
+    def _prefill_slots(self, queue: RequestQueue, free: list[int],
                        slots, emitted, gen_buf, lens, tok,
                        clock: float, caches):
-        """One prefill job placing ``batch`` into the free ``take`` slots.
+        """Admit a batch from ``queue`` and prefill it into the ``free``
+        slots as one prefill job, marking ``prefill.admit``, the engine's
+        ``prefill.dispatch``, ``prefill`` wait and ``prefill.pull``, then
+        ``prefill.account`` and ``prefill.place``.
 
-        Returns ``(clock, caches)`` — the advanced virtual clock and the
-        (merged) live caches.
+        Returns ``(clock, caches, admitted)`` — the advanced virtual clock,
+        the (merged) live caches, and whether a batch was admitted.
         """
+        m = self.metrics
+        lane = self._lane
+        args = {} if lane else None
+        lane.mark("loop", "prefill.admit", args)
+        batch = self._form_wave(queue, clock, limit=len(free))
+        if not batch:
+            return clock, caches, False
+        if args is not None:
+            args["rids"] = [r.rid for r in batch]
+        take = free[:len(batch)]
+        m.waves += 1
+        if len(free) < self.max_batch:
+            m.mid_wave_admissions += len(batch)
         clock = self._serve_handoff(batch, clock)
         plan, prompt_len = self._plan_prefill(batch, clock)
         wall = None
         next_tok = None
         if self.engine is not None:
             tokens, mask = self._stage_prefill_inputs(batch, take, prompt_len)
-            next_tok, caches, wall = self.engine.prefill_into_slots(
-                tokens, caches, mask, self.metrics)
-            self._record_wall(wall, "prefill")
+            with self._steps(args, lane):
+                next_tok, caches, wall = self.engine.prefill_into_slots(
+                    tokens, caches, mask, m)
+            m.step_wall_s.add(wall)
+        lane.mark("loop", "prefill.account", args)
         t_job = self._job_runtime(plan, wall)
         self._account_job(plan, t_job, self._executed_n(plan, prompt_len),
                           now=clock + t_job)
         self._trace_job(plan, clock, t_job)
         clock += t_job
+        lane.mark("loop", "prefill.place", args)
         self._place_prefilled(batch, take, slots, emitted, gen_buf, lens,
                               tok, t_job, clock, next_tok)
-        return clock, caches
+        for i in take:
+            if slots[i] is not None and emitted[i] >= slots[i].gen_len:
+                self._complete_request(slots[i], queue, clock, gen_buf[i])
+                slots[i] = None
+        return clock, caches, True
 
     # ------------------------------------------------------------------ #
     # Pipelined serving loop (async fabric protocol) — DESIGN.md §7
@@ -1207,8 +1297,10 @@ class ContinuousBatcher:
                 plan.m if plan.offload else None, plan.n_elems,
                 t_submit=clock, offload=plan.offload)
             if self.engine is not None:
-                next_tok, caches_d, wall = self.engine.wait_step(pending_d)
-                self._record_wall(wall, "decode")
+                with self._steps():
+                    next_tok, caches_d, wall = self.engine.wait_step(
+                        pending_d)
+                m.step_wall_s.add(wall)
                 if inflight is None or inflight.pending is None:
                     caches = caches_d
                 # else: the decode's caches were donated into the in-flight
@@ -1278,8 +1370,10 @@ class ContinuousBatcher:
                 # Nothing overlapped it (idle fabric): dispatch now.
                 inflight.pending = self.engine.prefill_into_slots_async(
                     inflight.tokens, caches, inflight.mask, m)
-            next_tok, caches, wall = self.engine.wait_step(inflight.pending)
-            self._record_wall(wall, "prefill")
+            with self._steps():
+                next_tok, caches, wall = self.engine.wait_step(
+                    inflight.pending)
+            m.step_wall_s.add(wall)
         job = self._complete(inflight.handle, wall)
         plan = inflight.plan
         self._account_job(plan, job.effective,
@@ -1315,8 +1409,10 @@ class ContinuousBatcher:
             tokens = np.zeros((self.max_batch, prompt_len), np.int32)
             for slot, r in enumerate(wave):
                 tokens[slot] = r.tokens
-            next_tok, caches, wall = self.engine.prefill(tokens, self.metrics)
-            self._record_wall(wall, "prefill")
+            with self._steps():
+                next_tok, caches, wall = self.engine.prefill(tokens,
+                                                             self.metrics)
+            m.step_wall_s.add(wall)
         t_job = self._job_runtime(plan, wall)
         self._account_job(plan, t_job, self._executed_n(plan, prompt_len),
                           now=clock + t_job)
@@ -1342,9 +1438,10 @@ class ContinuousBatcher:
                                          kind="decode", now=clock)
             wall = None
             if self.engine is not None:
-                next_tok, caches, wall = self.engine.decode(
-                    tok, caches, prompt_len + step)
-                self._record_wall(wall, "decode")
+                with self._steps():
+                    next_tok, caches, wall = self.engine.decode(
+                        tok, caches, prompt_len + step)
+                m.step_wall_s.add(wall)
                 tok = next_tok[:, None].astype(np.int32)
             t_dec = self._job_runtime(plan_d, wall)
             self._account_job(plan_d, t_dec, self._executed_n(plan_d, None),
