@@ -187,11 +187,14 @@ def make_slot_prefill_step(cfg: ModelConfig, mesh, batch_abstract, *,
 def make_decode_step(cfg: ModelConfig, mesh, specs, *,
                      unroll_groups: bool = False,
                      fused: bool = False) -> StepBundle:
-    """specs: {"tokens": (B,1), "caches": pytree, "cache_len": scalar|(B,)}.
+    """specs: {"tokens": (B,1)|(B,), "caches": pytree,
+    "cache_len": scalar|(B,)}.
 
     A per-slot ``cache_len`` vector lets each batch row decode at its own
     sequence offset (continuous batching, DESIGN.md §6); a scalar keeps the
-    legacy batch-wide position (every row at the same offset).
+    legacy batch-wide position (every row at the same offset).  ``(B,)``
+    tokens have the shape and sharding of the step's own ``next_token``, so
+    a step can take its predecessor's output where it lies on the devices.
 
     ``fused=True`` builds the step on the fused Pallas decode-attention
     kernel (one launch per layer, bit-identical tokens — DESIGN.md §12).
@@ -199,6 +202,8 @@ def make_decode_step(cfg: ModelConfig, mesh, specs, *,
     ctx = make_shard_ctx(mesh)
 
     def decode_fn(params, tokens, caches, cache_len):
+        if tokens.ndim == 1:
+            tokens = tokens[:, None]
         logits, new_caches = model_decode(params, cfg, tokens, caches,
                                           cache_len, ctx=ctx, fused=fused)
         next_tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)

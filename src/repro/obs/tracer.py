@@ -195,9 +195,11 @@ class WallLane:
         self._open = None      # (track, name, args, start, annotation)
 
     def mark(self, track: str, name: str, args: dict | None = None) -> None:
+        # The profiler stamps an annotation when it is made: read the clock
+        # right after, before any other work of the mark.
+        ann = self.tracer.annotation(name)
         t = time.perf_counter()
         self._end(t)
-        ann = self.tracer.annotation(name)
         ann.__enter__()
         self._open = (track, name, args, t, ann)
 

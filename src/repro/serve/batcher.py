@@ -84,6 +84,17 @@ class PendingStep:
     kind: str = "decode"           # "decode" | "prefill"
 
 
+@dataclasses.dataclass
+class _RunAhead:
+    """A decode dispatched on its predecessor's device outputs before the
+    predecessor was waited on (:meth:`ServingEngine.run_ahead`)."""
+
+    step: PendingStep
+    lens: np.ndarray               # its cache lengths
+    advanced: np.ndarray           # rows whose length it advanced
+    tokens: np.ndarray | None = None   # its input tokens, once pulled
+
+
 class ServingEngine:
     """Compiled prefill/decode steps over fixed request slots."""
 
@@ -122,6 +133,13 @@ class ServingEngine:
         # are traced only inside a serving loop's ``step_spans`` scope.
         self.tracer = tracer if tracer is not None else NULL
         self._spans = None    # the open step_spans scope, if any
+        # Decode run-ahead (:meth:`run_ahead`): only where a row's step
+        # depends on that row alone (no expert capacity shared across the
+        # batch) and its cache is attention KV, so that a step run again
+        # over a run-ahead's output caches equals the step (no SSM state).
+        self.runs_ahead = set(cfg.pattern) <= {"attn", "local"}
+        self._armed: np.ndarray | None = None
+        self._ahead: _RunAhead | None = None
 
         with self.mesh:
             caches_abs = jax.eval_shape(
@@ -132,7 +150,9 @@ class ServingEngine:
                 lambda: init_cache(cfg, max_batch, max_len=max_len),
                 out_shardings=self._cache_shardings)
             dec = make_decode_step(cfg, self.mesh, {
-                "tokens": jax.ShapeDtypeStruct((max_batch, 1), jnp.int32),
+                # (B,): a host token column or the previous step's
+                # ``next_token`` as it lies on the devices, one program.
+                "tokens": jax.ShapeDtypeStruct((max_batch,), jnp.int32),
                 "caches": caches_abs,
                 # Per-slot cache lengths: each row decodes at its own offset.
                 "cache_len": jax.ShapeDtypeStruct((max_batch,), jnp.int32),
@@ -141,6 +161,10 @@ class ServingEngine:
                 dec.fn, in_shardings=dec.in_shardings,
                 out_shardings=dec.out_shardings,
                 donate_argnums=dec.donate_argnums)
+            # Host tokens are placed as the step's ``next_token`` lies, so
+            # that host and device tokens call one executable (an array not
+            # committed to a sharding would call a second one).
+            self._dec_tok_sharding = dec.out_shardings["next_token"]
             # Parameters are drawn on the devices, in their own dtype and
             # sharding: no float32 copy, and each device makes only its
             # shard.  Same values as eager ``init_params`` for one seed.
@@ -253,7 +277,8 @@ class ServingEngine:
         keep rejecting refits).  ``slots=True`` warms the prefill-into-slot
         path (continuous batching) instead of the wave prefill.
         """
-        for length in sorted(set(prompt_lens)):
+        lengths = sorted(set(prompt_lens))
+        for length in lengths:
             # One ``setup.warmup`` span per length: its compiles (or
             # compile-cache loads) and its first run.
             with self.tracer.wall("engine", "setup", "setup.warmup",
@@ -269,29 +294,102 @@ class ServingEngine:
                 else:
                     _, caches, _ = self.prefill(tokens)
                 tok = np.zeros((self.max_batch, 1), np.int32)
+                if length == lengths[0] and self.run_ahead(
+                        self._lens(length)):
+                    # One decode run ahead, as the serving loop runs them:
+                    # its device-token input is warmed with the rest.
+                    _, caches, _ = self.decode(tok, caches, length)
                 self.decode(tok, caches, length)
+                # A run-ahead's caches are live: free them before the next
+                # length's are made, or the two would count in the peak.
+                del caches
 
-    def decode_async(self, tok: np.ndarray, caches, lens) -> PendingStep:
-        """Dispatch one decode step without blocking on its completion."""
-        jnp = self._jnp
-        self._mark("decode.dispatch")
+    def _lens(self, lens) -> np.ndarray:
+        """Per-slot cache lengths as a (max_batch,) int32 vector."""
         lens = np.asarray(lens, np.int32)
         if lens.ndim == 0:
             lens = np.full((self.max_batch,), int(lens), np.int32)
+        return lens
+
+    def decode_async(self, tok, caches, lens) -> PendingStep:
+        """Dispatch one decode step without blocking on its completion.
+
+        ``tok`` is a host array of the slots' last tokens, (max_batch, 1) or
+        (max_batch,), or a previous step's ``next_token`` where it lies on
+        the devices (a run-ahead step: no copy to or from the host).
+        """
+        jax, jnp = self._jax, self._jnp
+        on_device = isinstance(tok, jax.Array)
+        self._mark("decode.dispatch", {"chained": on_device})
+        if not on_device:
+            tok = jax.device_put(
+                np.asarray(tok, np.int32).reshape(self.max_batch),
+                self._dec_tok_sharding)
         with self.mesh:
-            out = self._dec_jit(self.params, jnp.asarray(tok), caches,
-                                jnp.asarray(lens))
+            out = self._dec_jit(self.params, tok, caches,
+                                jnp.asarray(self._lens(lens)))
         return PendingStep(out=out)
 
-    def decode(self, tok: np.ndarray, caches, lens):
+    def run_ahead(self, lens) -> bool:
+        """Arm the next :meth:`decode` to run ahead: to dispatch the step
+        after its own, on its ``next_token`` and caches where they lie on
+        the devices and at cache lengths ``lens``, before it waits on its
+        own step.  The host's work between two steps then overlaps the
+        device's.  Returns False, arming nothing, where the engine does not
+        run ahead (:attr:`runs_ahead`).
+        """
+        if not self.runs_ahead:
+            return False
+        self._armed = self._lens(lens).copy()
+        return True
+
+    def decode(self, tok, caches, lens):
         """tok (max_batch, 1) int32 -> (next_token (B,), caches, wall_s).
 
         ``lens`` is the per-slot cache length — an int (every slot at the
         same position) or a (max_batch,) vector (continuous batching).
         ``wall_s`` is the CreditCounterSync blocking wait on the credit
-        scalar — the host-observed completion latency of the step.
+        scalar — the host-observed completion latency of the step; for a
+        step already dispatched by a run-ahead, the residual wait.
+
+        Armed by :meth:`run_ahead`, it returns the caches of the step run
+        ahead, still in flight: the next call given those caches, the armed
+        lengths and, in the rows the lengths advanced, the tokens returned
+        here, waits on that step instead of dispatching one.  Given other
+        tokens it runs the step again over those caches, which equals the
+        step over the caches before: a decode writes each row's KV entry at
+        its position before it reads the row's cache there, and the step
+        run ahead wrote nothing else.
         """
-        return self.wait_step(self.decode_async(tok, caches, lens))
+        pending = self._take_ahead(tok, caches, lens)
+        if pending is None:
+            pending = self.decode_async(tok, caches, lens)
+        armed, self._armed = self._armed, None
+        if armed is not None:
+            self._ahead = _RunAhead(
+                self.decode_async(pending.out["next_token"],
+                                  pending.out["caches"], armed),
+                armed, armed != self._lens(lens))
+        next_tok, caches, wall = self.wait_step(pending)
+        if armed is not None:
+            self._ahead.tokens = next_tok
+            caches = self._ahead.step.out["caches"]
+        return next_tok, caches, wall
+
+    def _take_ahead(self, tok, caches, lens) -> PendingStep | None:
+        """The step run ahead, if these are its inputs; a run-ahead whose
+        caches the caller did not pass back is dropped."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None or caches is not ahead.step.out["caches"]:
+            return None
+        if not np.array_equal(self._lens(lens), ahead.lens):
+            raise ValueError("a decode over a run-ahead's caches must take "
+                             "the cache lengths it was armed with")
+        rows = ahead.advanced
+        same = np.array_equal(
+            np.asarray(tok, np.int32).reshape(self.max_batch)[rows],
+            ahead.tokens[rows])
+        return ahead.step if same else None
 
     def step_ready(self, pending: PendingStep) -> bool:
         """Non-blocking completion probe of an in-flight step."""
@@ -319,10 +417,13 @@ class ServingEngine:
         finally:
             self._spans = None
 
-    def _mark(self, name: str) -> None:
-        """Start ``name`` on the lane of the open :meth:`step_spans`."""
+    def _mark(self, name: str, extra: dict | None = None) -> None:
+        """Start ``name`` on the lane of the open :meth:`step_spans`, its
+        args those of the scope plus ``extra``."""
         if self._spans is not None:
             _, _, args, lane = self._spans
+            if extra and args is not None:
+                args = {**args, **extra}
             lane.mark("engine", name, args)
 
     def wait_step(self, pending: PendingStep):
@@ -985,7 +1086,11 @@ class ContinuousBatcher:
         decode step, and ``prefill.*`` for an admission
         (:meth:`_prefill_slots`).  Each span lasts until the next mark, so
         the loop's return to its top counts to the span before it.  No span
-        encloses a whole iteration."""
+        encloses a whole iteration.
+
+        Where :meth:`_may_run_ahead` allows, the engine runs the next decode
+        ahead: its ``decode.dispatch`` (``chained``) comes before this
+        step's wait, and the next iteration has none of its own."""
         m = self.metrics
         nb = self.max_batch
         lane = self._lane
@@ -1038,6 +1143,11 @@ class ContinuousBatcher:
                                        now=clock)
             wall = None
             if self.engine is not None:
+                if self._may_run_ahead(occ, slots, emitted, queue):
+                    ahead = lens.copy()
+                    ahead[occ] += 1
+                    if self.engine.run_ahead(ahead):
+                        m.decode_chained += 1
                 with self._steps(args, lane):
                     next_tok, caches, wall = self.engine.decode(tok, caches,
                                                                 lens)
@@ -1061,6 +1171,19 @@ class ContinuousBatcher:
                 if emitted[i] >= slots[i].gen_len:
                     finish(i, clock)
             self._maybe_checkpoint(slots, emitted, lens, gen_buf, clock)
+
+    def _may_run_ahead(self, occ: list[int], slots, emitted,
+                       queue: RequestQueue) -> bool:
+        """Whether the step after this decode is sure to be the next decode
+        over the same slots, however long this one takes: no slot finishes
+        at this step, no admission can follow it (no slot is free, or no
+        request is left to admit), and no fault or preemption can come
+        between.  Only then may it be dispatched before this one is waited
+        on (DESIGN.md §6): the loop's order of events, and every virtual
+        time, stay as they are."""
+        return (self.faults is None and not self.preempt
+                and (len(occ) == self.max_batch or queue.empty)
+                and all(emitted[i] + 1 < slots[i].gen_len for i in occ))
 
     def _plan_prefill(self, batch: list[Request],
                       clock: float) -> tuple[BatchPlan, int]:

@@ -101,6 +101,9 @@ class ServeMetrics:
     goodput_completed: int = 0    # completed with SLO met (or no SLO)
     # Pipelined-serving counters (DESIGN.md §7).
     pipelined_prefills: int = 0   # prefills dispatched under in-flight work
+    # Decode run-ahead (DESIGN.md §6): decode steps dispatched while the
+    # previous decode was still in flight.
+    decode_chained: int = 0
     # Energy accounting (DESIGN.md §11): joules attributed to completed
     # jobs, accumulated from the fabric's deterministic closed-form pricing
     # on every serving path identically.

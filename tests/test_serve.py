@@ -347,6 +347,9 @@ class _StubEngine:
     def decode(self, tok, caches, lens):
         return np.zeros(self.max_batch, np.int32), caches, 1e-6
 
+    def run_ahead(self, lens):
+        return False
+
 
 @pytest.mark.parametrize("wave_boundary", [False, True])
 def test_wallclock_calibration_uses_executed_batch_size(wave_boundary):
@@ -544,6 +547,195 @@ def test_continuous_mixed_length_slots_match_wave_boundary_tokens():
     for rw, rc in zip(wave["requests"], cont["requests"]):
         assert rw.rid == rc.rid
         np.testing.assert_array_equal(rw.generated, rc.generated)
+
+
+@pytest.mark.parametrize("runs_ahead", [True, False])
+def test_run_ahead_chains_between_admissions_and_finishes(runs_ahead):
+    """Decode run-ahead on a backlog, counted by hand (max_batch 2).
+
+    r0 (4 tokens) and r1 (8) fill both slots; r2 (3) waits.  Each prefill
+    emits a request's first token.  Step 1 runs step 2 ahead, which runs
+    step 3 ahead; r0 finishes at step 3, so step 3 runs nothing ahead and r2
+    is admitted.  Step 4 runs step 5 ahead; r2 finishes at step 5.  With a
+    slot free and the queue empty, step 6 runs step 7, r1's last, ahead.
+    Seven decode steps, three chain starts (steps 1, 4 and 6), so four run
+    ahead — and the tokens are the wave-boundary path's."""
+    from repro.serve import ServingEngine
+
+    rng = np.random.default_rng(7)
+    gens = (4, 8, 3)
+    prompts = [rng.integers(0, 128, size=(8,), dtype=np.int32)
+               for _ in gens]
+
+    def run(wave_boundary):
+        engine = ServingEngine("chatglm3-6b", reduced=True, max_batch=2,
+                               max_len=16)
+        engine.runs_ahead = runs_ahead
+        cal = OnlineCalibrator()
+        sched = OffloadAwareScheduler(cal, available_m=AVAILABLE)
+        b = ContinuousBatcher(sched, cal,
+                              fabric=SimulatedFabric(jitter_pct=0.0),
+                              engine=engine, wave_boundary=wave_boundary)
+        return b.run([Request(rid=i, arrival=0.0, prompt_len=8, gen_len=g,
+                              tokens=prompts[i])
+                      for i, g in enumerate(gens)])
+
+    wave, cont = run(True), run(False)
+    m = cont["metrics"]
+    steps = len(m.slot_occupancy)
+    assert steps == 7
+    assert m.decode_chained == (steps - 3 if runs_ahead else 0)
+    for rw, rc in zip(wave["requests"], cont["requests"]):
+        np.testing.assert_array_equal(rw.generated, rc.generated)
+
+
+@pytest.mark.parametrize("same_tokens", [True, False],
+                         ids=["its_tokens", "other_tokens"])
+def test_decode_after_run_ahead_equals_the_step_in_order(same_tokens):
+    """The decode after an armed one returns the step over the caches
+    before, whether it takes the step run ahead (its own tokens) or runs
+    the step again (other tokens, e.g. a caller that changed them)."""
+    from repro.serve import ServingEngine
+
+    eng = ServingEngine("chatglm3-6b", reduced=True, max_batch=2, max_len=16)
+    prompt = np.random.default_rng(1).integers(0, 128, size=(2, 8),
+                                               dtype=np.int32)
+    mask = np.ones(2, bool)
+
+    def prefilled():
+        first, caches, _ = eng.prefill_into_slots(prompt, eng.init_caches(),
+                                                  mask)
+        return first[:, None], caches
+
+    tok, caches = prefilled()
+    assert eng.run_ahead(np.full(2, 9))
+    step1, caches, _ = eng.decode(tok, caches, 8)
+    tok2 = step1 if same_tokens else (step1 + 1) % eng.cfg.vocab_size
+    step2, _, _ = eng.decode(tok2[:, None], caches, 9)
+
+    tok, caches = prefilled()                 # the same steps, one by one
+    want1, caches, _ = eng.decode(tok, caches, 8)
+    want2, _, _ = eng.decode(tok2[:, None], caches, 9)
+    np.testing.assert_array_equal(step1, want1)
+    np.testing.assert_array_equal(step2, want2)
+
+
+def _served(monkeypatch, run_ahead: bool, **config):
+    """A small served job and the number of decode steps the engine
+    dispatched; ``run_ahead=False`` serves it with the loop's run-ahead rule
+    switched off, as the loop ran before it had one."""
+    from repro.serve import ServingEngine
+
+    if not run_ahead:
+        monkeypatch.setattr(ContinuousBatcher, "_may_run_ahead",
+                            lambda self, *a: False)
+    dispatched = []
+    dispatch = ServingEngine.decode_async
+    monkeypatch.setattr(ServingEngine, "decode_async",
+                        lambda self, *a: dispatched.append(1)
+                        or dispatch(self, *a))
+    spec = WorkloadSpec(num_requests=10, seed=5, prompt_lens=(8, 16),
+                        gen_lens=(3, 6, 9), rate_rps=400_000.0)
+    out = serve_workload(spec, config=ServeConfig(max_batch=3, **config))
+    monkeypatch.undo()
+    return out, len(dispatched)
+
+
+@pytest.mark.parametrize("config", [
+    {"execute": True, "faults": "stall@0:0.3+0.1"},
+    {"execute": False},
+    {"execute": True}],
+    ids=["faults_engine", "poisson", "poisson_engine"])
+def test_run_ahead_leaves_simulated_results_as_they_were(monkeypatch,
+                                                         config):
+    """On the simulated fabric every result is bit-identical with and
+    without run-ahead: a fault injector or a missing engine keeps it off,
+    and where it does run (an engine on Poisson arrivals) it moves no
+    admission and no virtual time, and every step it runs ahead is used."""
+    before, _ = _served(monkeypatch, False, **config)
+    after, dispatched = _served(monkeypatch, True, **config)
+    m0, m1 = before["metrics"], after["metrics"]
+    s0, s1 = m0.summary(), m1.summary()
+    s0.pop("wall"), s1.pop("wall")         # the engine's real-clock seconds
+    assert s1 == s0
+    assert [(p.kind, p.n_elems, p.m, p.t_pred) for p in after["plans"]] == \
+        [(p.kind, p.n_elems, p.m, p.t_pred) for p in before["plans"]]
+    for r0, r1 in zip(before["requests"], after["requests"]):
+        assert (r0.rid, r0.t_done) == (r1.rid, r1.t_done)
+        np.testing.assert_array_equal(r0.generated, r1.generated)
+    if config.get("faults"):
+        assert m1.stalls > 0
+    if config.get("faults") or not config["execute"]:
+        assert m1.decode_chained == 0
+    else:
+        assert m1.decode_chained > 0
+    if config["execute"]:
+        assert dispatched == len(m1.slot_occupancy)   # one per decode step
+
+
+def test_warmup_run_ahead_frees_its_caches_before_the_next_length():
+    """Warm-up's run-ahead leaves no cache alive when the next prompt
+    length's caches are made, so the device's peak holds one cache."""
+    import gc
+
+    import jax
+
+    from repro.serve import ServingEngine
+
+    engine = ServingEngine("chatglm3-6b", reduced=True, max_batch=2,
+                           max_len=32)
+    make = engine.init_caches
+    live = []
+
+    def init_caches():
+        gc.collect()
+        live.append(sum(a.nbytes for a in jax.live_arrays()
+                        if not a.is_deleted()))
+        return make()
+
+    engine.init_caches = init_caches
+    engine.warmup((8, 16, 24), slots=True)
+    assert len(live) == 3 and len(set(live)) == 1
+
+
+def test_run_ahead_compiles_nothing_after_warmup():
+    """Host tokens and a run-ahead step's device tokens call one decode
+    executable, which warm-up compiles: no jit cache grows in the loop and
+    nothing compiles there."""
+    import jax
+
+    from repro.serve import ServingEngine, WallClockFabric
+
+    engine = ServingEngine("chatglm3-6b", reduced=True, max_batch=3,
+                           max_len=24)
+    lengths = (8, 16)
+    engine.warmup(lengths, slots=True)
+    jits = [engine._dec_jit, *engine._slot_prefill_jit.values()]
+    sizes = [f._cache_size() for f in jits]
+    cal = OnlineCalibrator()
+    sched = OffloadAwareScheduler(cal, available_m=AVAILABLE,
+                                  host_model=lambda n: float("inf"))
+    b = ContinuousBatcher(sched, cal, fabric=WallClockFabric(), engine=engine)
+    rng = np.random.default_rng(3)
+    compiles = []
+
+    def on_duration(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        out = b.run([Request(rid=i, arrival=0.0, prompt_len=pl, gen_len=g,
+                             tokens=rng.integers(0, 128, size=(pl,),
+                                                 dtype=np.int32))
+                     for i, (pl, g) in enumerate(
+                         [(8, 5), (16, 7), (8, 4), (16, 6), (8, 3)])])
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert out["metrics"].completed == 5
+    assert out["metrics"].decode_chained > 0
+    assert [f._cache_size() for f in jits] == sizes
+    assert engine._dec_jit._cache_size() == 1 and compiles == []
 
 
 @pytest.mark.parametrize("argv,reduced", [

@@ -118,13 +118,35 @@ def test_each_decode_step_records_its_spans_in_order(traced):
     spans = [e for e in _wall(tracer) if e.name in DECODE]
     steps = len([e for e in spans if e.name == "decode"])
     assert steps > 0
-    assert [e.name for e in spans] == list(DECODE) * steps
+    # A step's own dispatch is missing where the step before ran it ahead;
+    # a step that runs the next one ahead dispatches it (``chained``)
+    # before its own wait.
+    groups = []
+    for e in spans:
+        if e.name == "decode.plan":
+            groups.append([])
+        groups[-1].append(e)
+    assert len(groups) == steps
+    ran_ahead = False
+    for group in groups:
+        chained = [e.args["chained"] for e in group
+                   if e.name == "decode.dispatch"]
+        own = [] if ran_ahead else [False]
+        assert chained in (own, own + [True])
+        assert [e.name for e in group] == \
+            ["decode.plan"] + ["decode.dispatch"] * len(chained) + \
+            list(DECODE[2:])
+        ran_ahead = chained[-1:] == [True]
+    assert not ran_ahead
+    n_chained = sum(e.args.get("chained", False) for e in spans)
+    assert n_chained == out["metrics"].decode_chained > 0
     for a, b in zip(spans, spans[1:]):
         assert a.ts + a.dur <= b.ts + 1e-9     # in order, no overlap
     assert t0 <= spans[0].ts and spans[-1].ts + spans[-1].dur <= t1
     for e in spans:
-        assert e.args == {"rows": e.args["rows"]} and \
-            1 <= e.args["rows"] <= 4
+        keys = {"rows", "chained"} if e.name == "decode.dispatch" \
+            else {"rows"}
+        assert set(e.args) == keys and 1 <= e.args["rows"] <= 4
     # A prefill's spans come in order, and all carry its batch.
     pre = [e for e in _wall(tracer) if e.name in PREFILL]
     n = len([e for e in pre if e.name == "prefill"])
