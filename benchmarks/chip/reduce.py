@@ -10,7 +10,8 @@ trace and on hand-made events alike:
   * :func:`busy_ns`: the union of a device's op intervals;
   * :func:`program_time`: the time and runs of each compiled program whose
     name holds a given fragment (``decode_fn``, ``slot_prefill_step``);
-  * :func:`collective_ns`: the union of the collective ops' intervals;
+  * :func:`collective_ns`: the union of the collective ops' intervals
+    (:func:`is_collective`);
   * :func:`top_ops` and :func:`idle_gaps`: the ``breakdown`` of a result.
 """
 
@@ -27,6 +28,11 @@ MODULES_LINE = "XLA Modules"
 #: Opcodes of the ops that move data between chips.
 COLLECTIVE = re.compile(r"^(all-reduce|all-gather|reduce-scatter|"
                         r"collective-permute|all-to-all)(-start|-done)?$")
+#: The TPU compiler wraps some collectives in fusions of their own: the
+#: ``async-collective-start`` and ``-done`` pair, and fusions that call an
+#: ``async_collective_fusion`` computation.
+ASYNC_COLLECTIVE = re.compile(r"^async-collective-(start|done)(\.\d+)?$")
+_CALLS_COLLECTIVE = re.compile(r"calls=%async_collective_fusion[.\d]*\b")
 #: An op event is named by its HLO text: ``%name = <type> opcode(...)``.
 _HLO = re.compile(r"^%?(?P<name>[^\s=]+)(?: = (?P<rest>.*))?$", re.DOTALL)
 _OPCODE = re.compile(r"(?<![A-Za-z0-9_.-])([a-z][a-z0-9-]*)\(")
@@ -117,10 +123,18 @@ def program_time(events, device: int, fragment: str) -> tuple[float, int]:
     return sum(e.dur_ns for e in runs), len(runs)
 
 
+def is_collective(text: str) -> bool:
+    """An op event that moves data between chips: a collective opcode, or
+    one of the TPU compiler's async collective fusions."""
+    return bool(COLLECTIVE.match(opcode(text))
+                or ASYNC_COLLECTIVE.match(op_name(text))
+                or _CALLS_COLLECTIVE.search(text))
+
+
 def collective_ns(events, device: int) -> float:
     """Nanoseconds in which a collective op ran on ``device``."""
     ops = [o for o in _select(events, device, OPS_LINE)
-           if COLLECTIVE.match(opcode(o.name))]
+           if is_collective(o.name)]
     return sum(e - s for s, e in union((o.start_ns, o.end_ns) for o in ops))
 
 

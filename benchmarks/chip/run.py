@@ -20,13 +20,15 @@ process start to the first admission; its phases are printed on stderr.
 Everything that belongs to one configuration, traffic mix, cell or
 per-layer metric is a file found by its name in ``BENCHMARK.json``:
 ``configs/<config>.json``, ``traffic/<mix>.json``, ``cells/<cell>.json``
-and ``metrics/<metric>.py``.
+and ``metrics/<metric>.py``; the configuration names its model description,
+``models/<description>.py`` (``work.description``), through which alone
+the harness reaches the model's sizes, work counts and reference.
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1`` attaches
 the program's tracer, takes a profiler trace of a few seconds inside the
 job and reports the per-layer metrics.  Either way the served tokens of a
-sample of requests are checked against the float32 reference
-(``reference.py``) once the window has closed.
+sample of requests are checked against the description's float32
+reference once the window has closed.
 
 The run exits 2 and prints no result where JAX finds no TPU or fewer chips
 than the cell asks for.
@@ -212,6 +214,8 @@ def reduce_trace(win: TraceWindow, reqs) -> tuple[dict, dict]:
         "prefill_s": pre_ns * 1e-9, "prefill_runs": pre_runs,
         "prefills": win.prefills(reqs),
         "devices": len(devs),
+        "collective_s": [reduce.collective_ns(events, d) * 1e-9
+                         for d in devs],
     }
     breakdown = {"device_ops": reduce.top_ops(events, d0),
                  "idle_gaps": reduce.idle_gaps(events, d0)}
@@ -235,10 +239,11 @@ def sample_for_check(done, rows: int, seed: int):
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def check_served(m: work.Dims, reqs, shape: tuple, check: dict, seed: int,
+def check_served(model, m, reqs, shape: tuple, check: dict, seed: int,
                  control: bool = False) -> tuple[int, dict, dict | None]:
     """(failed requests, {number: {value, limit}}, the control's numbers)
-    of the served job; ``shape`` pads the reference's rows to one size.
+    of the served job, judged by the description ``model``'s reference at
+    sizes ``m``; ``shape`` pads the reference's rows to one size.
 
     ``control`` also puts the fp8 control in the program's place on the
     same rows: its own first choices are scored as its ``logit_gap``, beside
@@ -259,9 +264,8 @@ def check_served(m: work.Dims, reqs, shape: tuple, check: dict, seed: int,
     sample = sample_for_check(done, check["rows"], seed)
     control_numbers = None
     if sample:
-        import reference
-        gaps = reference.widest_gaps(m, sample, shape=(check["rows"],) + shape,
-                                     control=control)
+        gaps = model.widest_gaps(m, sample, shape=(check["rows"],) + shape,
+                                 control=control)
         numbers["logit_gap"] = {"value": gaps["served"],
                                 "limit": check["limit"]}
         numbers["tokens_checked"] = {"value": gaps["tokens"],
@@ -325,13 +329,13 @@ def steady_window(reqs) -> tuple[float, float, float]:
 
 def run(cell_name: str, *, seed: int, seconds: float, trace: bool,
         cfg: dict, mix: dict, cell: dict, bench: dict,
-        reduced: bool = False, dims: work.Dims | None = None,
+        reduced: bool = False, dims=None,
         peak: dict | None = None, control: bool = False,
         log=print) -> dict:
     """Serve one job and return the result line as a dict.
 
     ``reduced`` serves the program's scaled-down model (tests on the CPU);
-    ``dims`` then gives its sizes to the reference, and ``peak`` stands in
+    ``dims`` then gives its sizes to the description, and ``peak`` stands in
     for the device's peaks.  ``control`` also judges the fp8 control in
     the program's place (``result["control"]``).
     """
@@ -352,7 +356,8 @@ def run(cell_name: str, *, seed: int, seconds: float, trace: bool,
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     jax.monitoring.register_event_listener(on_event)
 
-    m = dims or work.dims(cfg)
+    model = work.description(cfg)
+    m = dims or model.dims(cfg)
     dev = device_record()
     peak = peak or work.load_peaks(dev["kind"])
     n = math.ceil(cell["requests_per_s"] * seconds)
@@ -409,7 +414,7 @@ def run(cell_name: str, *, seed: int, seconds: float, trace: bool,
 
     shape = (int(prompts.max() + gens.max() - 1), int(gens.max()))
     failed, numbers, control_numbers = check_served(
-        m, reqs, shape, cell["check"], seed, control)
+        model, m, reqs, shape, cell["check"], seed, control)
     correct = judge(numbers)
 
     metrics = {}
@@ -433,13 +438,14 @@ def run(cell_name: str, *, seed: int, seconds: float, trace: bool,
         rec = {
             "job_s": job_s - win.overhead_s,
             "serve_metrics": serve_metrics, "engine_spans": spans,
-            "trace": tr, "dims": m, "peak": peak, "lengths": lengths,
+            "trace": tr, "dims": m, "model": model, "peak": peak,
+            "lengths": lengths,
             "max_batch": cfg["max_batch"],
             "decode_steps": len(spans.get("decode", [])),
             "decode_positions": work.decode_live_positions(
                 *zip(*lengths)) if lengths else 0,
             "decode_flops": sum(
-                work.request_flops(m, p, g) - work.prefill_flops(m, p)
+                model.request_flops(m, p, g) - model.prefill_flops(m, p)
                 for p, g in lengths),
             "memory_peak_bytes": mem_peak,
         }
@@ -521,15 +527,9 @@ def chip_ready(entry: dict, cfg: dict) -> bool:
               f"found", file=sys.stderr)
         return False
     from repro.configs import get_config
-    program = get_config(cfg["arch"])
-    m = work.dims(cfg)
-    mismatch = {k: (getattr(program, pk), getattr(m, k)) for k, pk in (
-        ("layers", "num_layers"), ("d", "d_model"), ("ff", "d_ff"),
-        ("heads", "num_heads"), ("kv_heads", "num_kv_heads"),
-        ("head_dim", "qk_head_dim"), ("vocab", "vocab_size"),
-        ("vocab_padded", "vocab_padded"), ("rope_variant", "rope_variant"),
-        ("rope_theta", "rope_theta"), ("eps", "norm_eps"),
-        ("dtype", "dtype")) if getattr(program, pk) != getattr(m, k)}
+    model = work.description(cfg)
+    mismatch = model.program_mismatch(get_config(cfg["arch"]),
+                                      model.dims(cfg))
     if mismatch:
         print(f"run.py: the program's {cfg['arch']} differs from "
               f"configs/{entry['config']}.json: {mismatch}", file=sys.stderr)

@@ -2,9 +2,9 @@
 
 It skips the harness's look for a chip (``run.chip_ready``) and drives
 the rest of ``run.run``: the stamped job through ``serve_workload``, the
-metrics and the check against the reference, whose sizes are those of
-``repro.models.scaled_down`` (float32, 2 layers, width 64, vocabulary
-128).
+metrics and the check against the description's reference, whose sizes
+are those of ``repro.models.scaled_down`` (float32, 2 layers, width 64,
+vocabulary 128).
 """
 
 import chip_paths  # noqa: F401  (before the modules below)
@@ -25,13 +25,13 @@ MIX = {"prompt_len": {"median": 10, "sigma": 0.5, "min": 4, "max": 16,
 SMALL_LIMIT = 0.02
 
 
-def dims(cfg_name: str) -> work.Dims:
-    cfg = work.load_config(cfg_name)
-    rope = cfg["rope_variant"]
-    return work.Dims(layers=2, d=64, ff=128, heads=4, kv_heads=2,
-                     head_dim=16, vocab=128, vocab_padded=128, eps=1e-5,
-                     rope_variant=rope, rope_theta=10_000.0,
-                     dtype="float32", chips=1)
+def dims(cfg: dict):
+    """The sizes of the program's scaled-down model of ``cfg``, in the
+    record of the model description ``cfg`` names."""
+    from repro.configs import get_config
+    from repro.models import scaled_down
+    return work.description(cfg).program_dims(
+        scaled_down(get_config(cfg["arch"])), chips=1)
 
 
 def small_run(cell_name="chatglm3-6b.chat-decode", *, seed=2**31 + 11,
@@ -48,6 +48,6 @@ def small_run(cell_name="chatglm3-6b.chat-decode", *, seed=2**31 + 11,
     cell["trace"] = {"open_after_s": open_after_s, "seconds": trace_s}
     return run.run(cell_name, seed=seed, seconds=seconds, trace=trace,
                    cfg=cfg, mix=MIX, cell=cell, bench=bench, reduced=True,
-                   dims=dims(config),
+                   dims=dims(cfg),
                    peak=work.load_peaks("TPU v5 lite"), control=control,
                    log=lambda s: None)

@@ -3,9 +3,17 @@
 Each test breaks the timed path underneath a whole run (at the program's
 scaled-down size, on the CPU, past the harness's look for a chip) in one
 way a served cell can go wrong, and sees ``correct`` come out false; the
-last puts the fp8 control in the program's place on the same rows and
-sees the harness's own ``judge`` find it not correct.
+four-chip cell's exchange between chips is left out on four virtual CPU
+devices, in a process of its own (``exchange.py``); the last puts the fp8
+control in the program's place on the same rows and sees the harness's
+own ``judge`` find it not correct.
 """
+
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -59,8 +67,7 @@ def _token_altered(monkeypatch):
     monkeypatch.setattr(ServingEngine, "decode", decode)
 
 
-#: fault -> (cell, plant).  The cells run on one chip, so no exchange
-#: between chips can be left out.
+#: fault -> (cell, plant), on one device.
 FAULTS = {
     "state_unchanged": ("chatglm3-6b.chat-decode", _decode_state_unchanged),
     "half_batch": ("chatglm3-6b.chat-decode", _half_batch_left_out),
@@ -75,6 +82,22 @@ def test_a_broken_timed_path_is_not_correct(fault, monkeypatch):
     res = small_run(cell, requests=8)
     gap = res["checks"]["logit_gap"]
     assert res["correct"] is False
+    assert gap["value"] > gap["limit"]
+
+
+def test_the_exchange_between_chips_left_out_is_not_correct():
+    here = Path(__file__).resolve().parent
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=str(here.parents[2] / "src"))
+    res = subprocess.run([sys.executable, str(here / "exchange.py")],
+                         env=env, capture_output=True, text=True,
+                         timeout=600)
+    assert res.returncode == 0, res.stderr[-3000:]
+    out = json.loads(res.stdout.strip().splitlines()[-1])
+    assert out["sound"]["correct"] is True
+    gap = out["broken"]["checks"]["logit_gap"]
+    assert out["broken"]["correct"] is False
     assert gap["value"] > gap["limit"]
 
 

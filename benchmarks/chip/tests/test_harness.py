@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 import chip_paths  # noqa: F401  (before the modules below)
+import contract
 import run
 import traffic
 import work
@@ -60,28 +61,8 @@ def test_benchmark_json_keys_and_names(bench):
     assert four <= max(1, len(bench["workloads"]) // 2)
 
 
-def test_every_named_file_resolves(bench):
-    configs = {c["name"] for c in bench["configs"]}
-    for c in bench["configs"]:
-        cfg = json.loads((ROOT / c["file"]).read_text())
-        assert c["file"] == f"benchmarks/chip/configs/{c['name']}.json"
-        assert sorted(cfg["reduced"]) == sorted(c["reduced"])
-        work.dims(cfg)
-    pairs = set()
-    for w in bench["workloads"]:
-        assert w["config"] in configs
-        assert (w["config"], w["traffic"]) not in pairs
-        pairs.add((w["config"], w["traffic"]))
-        traffic.load_mix(w["traffic"])
-        cell = run.load_cell(w["name"])
-        assert cell["requests_per_s"] > 0 and cell["check"]["limit"] > 0
-        assert work.load_config(w["config"])["chips"] == w["chips"]
-        assert len(run.metric_names(bench, w["name"], True)) >= 1
-        assert len(run.metric_names(bench, w["name"], False)) >= 2
-    for m in bench["per_layer"]:
-        assert callable(run.metric_reader(m["name"]))
-        for w in m.get("workloads", []):
-            assert w in {x["name"] for x in bench["workloads"]}
+def test_every_named_file_resolves():
+    contract.check_named_files(ROOT)
 
 
 def _cli(cwd):
@@ -201,6 +182,14 @@ def test_every_seed_gets_the_same_requests(mix_name, own_order):
                for x, y in zip(b, again))
 
 
+class _Served:
+    """A finished request as the reference reads it."""
+
+    def __init__(self, tokens, generated):
+        self.tokens, self.generated = tokens, generated
+        self.prompt_len, self.gen_len = len(tokens), len(generated)
+
+
 class _Req:
     def __init__(self, gen_len, **wall):
         self.gen_len, self.wall = gen_len, wall
@@ -223,18 +212,19 @@ def test_the_window_ends_when_the_backlog_does():
 def test_reference_draws_the_programs_weights_and_logits(rope, kv):
     import jax
     import jax.numpy as jnp
-    import reference
     from repro.models import ModelConfig, forward, init_params
 
     cfg = ModelConfig(name="t", family="dense", num_layers=3, d_model=64,
                       d_ff=96, vocab_size=100, vocab_pad_to=32, num_heads=4,
                       num_kv_heads=kv, head_dim=16, rope_variant=rope,
                       dtype="bfloat16")
-    m = work.Dims(layers=3, d=64, ff=96, heads=4, kv_heads=kv, head_dim=16,
-                  vocab=100, vocab_padded=128, eps=1e-5, rope_variant=rope,
-                  rope_theta=10_000.0, dtype="bfloat16", chips=1)
+    dense = work.description(work.load_config("chatglm3-6b"))
+    m = dense.Dims(layers=3, d=64, ff=96, heads=4, kv_heads=kv, head_dim=16,
+                   vocab=100, vocab_padded=128, eps=1e-5, rope_variant=rope,
+                   rope_theta=10_000.0, dtype="bfloat16", chips=1)
+    assert dense.program_mismatch(cfg, m) == {}
     p = jax.jit(lambda k: init_params(k, cfg))(jax.random.key(0))
-    w = reference.draw_weights(m)
+    w = dense.draw_weights(m)
     assert bool((p["embed"] == w["embed"]).all())
     assert bool((p["lm_head"][:, :100] == w["head"]).all())
     for i in range(3):
@@ -252,6 +242,101 @@ def test_reference_draws_the_programs_weights_and_logits(rope, kv):
     with jax.default_matmul_precision("highest"):
         want = forward(p32, cfg32, tokens=jnp.asarray(toks))[..., :100]
     read = np.tile(np.arange(12), (2, 1)).astype(np.int32)
-    got = reference.forward_logits(m, toks, read)["f32"]
+    got = dense.forward_logits(m, toks, read)["f32"]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5)
+    # The program's own first choice after each whole row, served as one
+    # token, lies on the reference's best.
+    first = np.asarray(want)[:, -1].argmax(-1)
+    reqs = [_Served(toks[i], [first[i]]) for i in range(2)]
+    gaps = dense.widest_gaps(m, reqs, control=True)
+    assert gaps["served"] <= 1e-5 and gaps["tokens"] == 2
+
+
+class _Random:
+    """A finished request whose prompt and served tokens are random."""
+
+    def __init__(self, rng, prompt_len, gen_len):
+        self.prompt_len, self.gen_len = prompt_len, gen_len
+        self.tokens = rng.integers(0, 100, prompt_len).astype(np.int32)
+        self.generated = rng.integers(0, 100, gen_len).astype(np.int32)
+
+
+def test_reference_draws_and_gaps_are_those_before_the_move():
+    """The weights' hash and the gaps, computed by reference.py before the
+    dense reference moved into models/dense_gqa.py: the move may change
+    neither a draw nor a gap."""
+    import hashlib
+
+    import jax
+    dense = work.description(work.load_config("chatglm3-6b"))
+    m = dense.Dims(layers=3, d=64, ff=96, heads=4, kv_heads=2, head_dim=16,
+                   vocab=100, vocab_padded=128, eps=1e-5, rope_variant="half",
+                   rope_theta=10_000.0, dtype="bfloat16", chips=1)
+    h = hashlib.sha256()
+    for leaf in jax.tree.leaves(dense.draw_weights(m)):
+        h.update(np.asarray(leaf.astype("float32")).tobytes())
+    assert h.hexdigest() == ("93659ad1b1bea01fc074c9bea18d5fda"
+                             "5beb4e9156b3a151a6343b7f37264275")
+    rng = np.random.default_rng(7)
+    reqs = [_Random(rng, 12, 5), _Random(rng, 7, 9), _Random(rng, 20, 3)]
+    gaps = dense.widest_gaps(m, reqs, control=True, shape=(4, 32, 10))
+    assert gaps["tokens"] == 17
+    assert gaps["served"] == pytest.approx(5.176538944244385, rel=1e-6)
+    assert gaps["control"] == pytest.approx(0.08494114875793457, rel=1e-6)
+
+
+def test_a_configuration_added_as_files_only_resolves_and_runs(tmp_path):
+    """In a copy of the benchmark, a configuration is added as new files
+    only (its file, its model description, its cell) and as new entries of
+    BENCHMARK.json: every named file resolves, and a run of the new cell
+    at the program's scaled-down size is correct.  No file of the copy
+    changes but BENCHMARK.json."""
+    chip = tmp_path / "benchmarks" / "chip"
+    shutil.copytree(CHIP, chip, ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in chip.rglob("*") if p.is_file()}
+
+    name, cell = "toy-dense", "toy-dense.chat-decode"
+    (chip / "models" / "toy_dense.py").write_text(
+        (CHIP / "models" / "dense_gqa.py").read_text())
+    cfg = dict(work.load_config("granite-3-8b-tp4"), name=name,
+               description="toy_dense", chips=1, mesh_shape=[1, 1])
+    (chip / "configs" / f"{name}.json").write_text(json.dumps(cfg))
+    (chip / "cells" / f"{cell}.json").write_text(json.dumps(
+        {"requests_per_s": 1.0,
+         "check": {"rows": 8, "min_tokens": 300, "limit": 0.2},
+         "trace": {"open_after_s": 15.0, "seconds": 4.0}}))
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench["configs"].append({
+        "name": name, "source": "https://example.org/toy", "reduced":
+        cfg["reduced"], "file": f"benchmarks/chip/configs/{name}.json",
+        "why": "a configuration brought as files only"})
+    bench["workloads"].append({"name": cell, "config": name,
+                               "traffic": "chat-decode", "chips": 1,
+                               "why": "files only"})
+    for m in bench["per_layer"]:
+        if m["name"] in ("host_share", "mfu"):
+            m["workloads"].append(cell)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    code = (
+        "import json, sys\n"
+        f"sys.path.insert(0, {str(chip / 'tests')!r})\n"
+        "from pathlib import Path\n"
+        "import contract, work\n"
+        f"contract.check_named_files(Path({str(tmp_path)!r}))\n"
+        f"assert work.description(work.load_config({name!r})).__file__ \\\n"
+        f"    == {str(chip / 'models' / 'toy_dense.py')!r}\n"
+        "from smallrun import small_run\n"
+        f"res = small_run({cell!r})\n"
+        "print(json.dumps({'correct': res['correct'],"
+        " 'checks': res['checks']}))\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT / "src"))
+    res = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stderr[-3000:]
+    out = json.loads(res.stdout.strip().splitlines()[-1])
+    assert out["correct"] is True, out
+    assert out["checks"]["tokens_checked"]["value"] > 0
+    after = {p: p.read_bytes() for p in before if p.is_file()}
+    assert after == before

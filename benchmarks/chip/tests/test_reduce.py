@@ -65,6 +65,29 @@ def test_collectives_are_found_by_opcode_not_operands():
     assert reduce.opcode(ev[6].name) == "all-gather-start"
 
 
+@pytest.mark.parametrize("text,want", [
+    ("%all-reduce.13 = f32[64,8,4] all-reduce(f32[64,8,4] %x), "
+     "replica_groups=[1,4]<=[4]", True),
+    ("%all-gather-start.2 = (bf16[8], bf16[32]) all-gather-start(%y)", True),
+    # The TPU compiler's async collective fusions (a decode step of the
+    # four-chip granite cell, compiled for a described 2x2 v5e).
+    ("%async-collective-start = (bf16[64,2,128], bf16[64,8,128]) "
+     "fusion(bf16[64,2,128] %p), kind=kCustom, "
+     "calls=%fused_computation.131", True),
+    ("%async-collective-done = bf16[64,8,128] fusion(%g.526), "
+     "kind=kCustom, calls=%fused_computation.134", True),
+    ("%fusion.175 = (bf16[64,2,128], bf16[64,8,128]) fusion(%a, %b), "
+     "kind=kCustom, calls=%async_collective_fusion.175", True),
+    # Ops that only read a collective's result are not collectives.
+    ("%fusion.1 = bf16[8] fusion(bf16[8] %all-reduce-done.3), "
+     "kind=kLoop, calls=%fused_computation.1", False),
+    ("%fusion.4 = f32[8] fusion(f32[8] %async-collective-done)", False),
+    ("%copy-start = bf16[8] copy-start(bf16[8] %x.1)", False),
+])
+def test_which_ops_are_collectives(text, want):
+    assert reduce.is_collective(text) is want
+
+
 def test_breakdown_lists_ops_and_named_gaps():
     ev = hand_events()
     assert reduce.top_ops(ev, 0, 2) == [["fusion.4", pytest.approx(25e-9)],
@@ -88,3 +111,32 @@ def test_recorded_tpu_trace_matches_hand_counts():
                       pytest.approx((11841 * 2 + 11840) * 1e-9)]
     assert [name for name, _ in ops] == ["convolution_reduce_fusion",
                                          "copy-start", "copy-done"]
+
+
+def _collective_share():
+    import run
+    return run.metric_reader("collective_share")
+
+
+@pytest.mark.parametrize("trace,want", [
+    # Four chips, 2 to 5 ms of collectives each in a 40 ms window.
+    ({"window_s": 0.04, "collective_s": [0.002, 0.003, 0.004, 0.005]},
+     100.0 * 0.0035 / 0.04),
+    # One chip runs no collective; a run with no trace reads nothing.
+    ({"window_s": 0.04, "collective_s": [0.0]}, None),
+    ({}, None),
+    (None, None),
+])
+def test_collective_share_is_the_chips_mean_over_the_window(trace, want):
+    read = _collective_share()
+    got = read({"trace": trace} if trace is not None else {})
+    assert got == (pytest.approx(want) if want is not None else None)
+
+
+def test_collective_share_of_hand_events_per_chip():
+    ev = hand_events()
+    per_chip = [reduce.collective_ns(ev, d) * 1e-9 for d in
+                reduce.devices(ev)]
+    got = _collective_share()({"trace": {"window_s": 50e-9,
+                                         "collective_s": per_chip}})
+    assert got == pytest.approx(100.0 * (5 + 6) / 2 / 50)

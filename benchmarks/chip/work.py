@@ -1,20 +1,20 @@
-"""Work counted from shapes: parameter and cache bytes, model FLOPs.
+"""What the work counts share: configurations, their model descriptions,
+the peak table and the roofline.
 
-Everything here is computed from a configuration file under
-``benchmarks/chip/configs`` and from request lengths, never from the
-program, so the yardstick stays put while the program changes.  Bytes are
-those a chip holds or must read; FLOPs count multiply and add as two.
-
-``dims(cfg)`` maps a configuration file's published keys onto the few sizes
-the counts need.  Per-chip figures divide by the chips a layer is split
-over: the tensor-parallel layout splits every matrix, the embedding, the
-output head and the cache four ways, and replicates only the norms.
+A configuration file under ``benchmarks/chip/configs`` names its model
+description by ``"description"``: the module ``models/<name>.py``, found
+by name as ``metrics/<metric>.py`` is.  The description turns the file's
+published keys into sizes and counts the work from them (parameter and
+cache bytes, model FLOPs), never from the program, so the yardstick stays
+put while the program changes.
 """
 
 from __future__ import annotations
 
+import importlib.util
 import json
-from dataclasses import dataclass
+import re
+import sys
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
@@ -22,125 +22,36 @@ HERE = Path(__file__).resolve().parent
 #: Bytes per element of the serving dtypes.
 DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
 
-#: Published key names of each ``model_type``.
-_KEYS = {
-    "chatglm": dict(layers="num_layers", d="hidden_size",
-                    ff="ffn_hidden_size", heads="num_attention_heads",
-                    kv_heads="multi_query_group_num", head_dim="kv_channels",
-                    vocab="padded_vocab_size", eps="layernorm_epsilon"),
-    "granite": dict(layers="num_hidden_layers", d="hidden_size",
-                    ff="intermediate_size", heads="num_attention_heads",
-                    kv_heads="num_key_value_heads", head_dim=None,
-                    vocab="vocab_size", eps="rms_norm_eps"),
-}
-
-
-@dataclass(frozen=True)
-class Dims:
-    """The sizes of a dense, gated-MLP, GQA decoder that the counts need."""
-
-    layers: int
-    d: int
-    ff: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    vocab: int            # real vocabulary (logits that are read)
-    vocab_padded: int     # rows held by the embedding and the output head
-    eps: float
-    rope_variant: str     # "half" (ChatGLM) or "full"
-    rope_theta: float
-    dtype: str
-    chips: int
-
-    @property
-    def elem_bytes(self) -> int:
-        return DTYPE_BYTES[self.dtype]
+_NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
 def load_config(name: str) -> dict:
     return json.loads((HERE / "configs" / f"{name}.json").read_text())
 
 
-def dims(cfg: dict) -> Dims:
-    keys = _KEYS[cfg["model_type"]]
-    d = int(cfg[keys["d"]])
-    heads = int(cfg[keys["heads"]])
-    head_dim = (int(cfg[keys["head_dim"]]) if keys["head_dim"]
-                else d // heads)
-    vocab = int(cfg[keys["vocab"]])
-    pad = int(cfg.get("vocab_pad_to", 1))
-    return Dims(layers=int(cfg[keys["layers"]]), d=d, ff=int(cfg[keys["ff"]]),
-                heads=heads, kv_heads=int(cfg[keys["kv_heads"]]),
-                head_dim=head_dim, vocab=vocab,
-                vocab_padded=-(-vocab // pad) * pad,
-                eps=float(cfg[keys["eps"]]),
-                rope_variant=cfg["rope_variant"],
-                rope_theta=float(cfg["rope_theta"]),
-                dtype=cfg.get("served_dtype", cfg["torch_dtype"]),
-                chips=int(cfg["chips"]))
-
-
-# --------------------------------------------------------------------------- #
-# Parameters and cache
-# --------------------------------------------------------------------------- #
-def layer_params(m: Dims) -> int:
-    """Matrix and norm elements of one decoder layer."""
-    attn = m.d * m.head_dim * (m.heads + 2 * m.kv_heads) \
-        + m.heads * m.head_dim * m.d
-    mlp = 3 * m.d * m.ff
-    return attn + mlp + 2 * m.d
-
-
-def param_count(m: Dims) -> int:
-    """Every element the chips hold: layers, embedding, head, final norm."""
-    return m.layers * layer_params(m) + 2 * m.vocab_padded * m.d + m.d
-
-
-def param_bytes(m: Dims) -> int:
-    """Bytes of all weights, over all chips."""
-    return param_count(m) * m.elem_bytes
-
-
-def kv_bytes_per_token(m: Dims) -> int:
-    """Cache bytes one position takes on one chip (keys and values)."""
-    return 2 * m.layers * m.kv_heads * m.head_dim * m.elem_bytes // m.chips
-
-
-def matmul_params(m: Dims) -> int:
-    """Weights one token multiplies through: every layer, the output head
-    over the real vocabulary.  The embedding is a lookup, not a product."""
-    return m.layers * (layer_params(m) - 2 * m.d) + m.d * m.vocab
-
-
-# --------------------------------------------------------------------------- #
-# FLOPs
-# --------------------------------------------------------------------------- #
-def attn_flops(m: Dims, kv_len: int) -> int:
-    """Scores and weighted values of one query over ``kv_len`` positions."""
-    return 4 * m.layers * m.heads * m.head_dim * kv_len
-
-
-def prefill_flops(m: Dims, prompt_len: int) -> int:
-    """Model FLOPs of one prompt: 2 per weight per token, causal attention."""
-    causal = prompt_len * (prompt_len + 1) // 2
-    return 2 * matmul_params(m) * prompt_len \
-        + 4 * m.layers * m.heads * m.head_dim * causal
-
-
-def decode_flops(m: Dims, kv_len: int) -> int:
-    """Model FLOPs of one generated token attending ``kv_len`` positions."""
-    return 2 * matmul_params(m) + attn_flops(m, kv_len)
-
-
-def request_flops(m: Dims, prompt_len: int, gen_len: int) -> int:
-    """All model FLOPs of one request: its prompt, then ``gen_len - 1``
-    decode steps (the first token comes from the prompt's pass), step ``k``
-    attending ``prompt_len + k`` positions."""
-    steps = gen_len - 1
-    kv_sum = steps * prompt_len + steps * (steps + 1) // 2
-    return prefill_flops(m, prompt_len) + 2 * matmul_params(m) * steps \
-        + 4 * m.layers * m.heads * m.head_dim * kv_sum
+def description(cfg: dict):
+    """The model description module ``cfg`` names (loaded once per
+    process); a missing or unknown name is an error that names the files."""
+    where = f"benchmarks/chip/configs/{cfg.get('name')}.json"
+    name = cfg.get("description")
+    if not isinstance(name, str) or not _NAME.match(name):
+        raise ValueError(f"{where} names no model description: its "
+                         f"'description' is {name!r}")
+    path = HERE / "models" / f"{name}.py"
+    if not path.is_file():
+        raise ValueError(f"{where} names the model description {name!r}, "
+                         f"and there is no benchmarks/chip/models/{name}.py")
+    key = f"chip_model_{name}"
+    if key not in sys.modules:
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[key] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[key]
+            raise
+    return sys.modules[key]
 
 
 def decode_live_positions(prompt_lens, gen_lens) -> int:
@@ -150,22 +61,6 @@ def decode_live_positions(prompt_lens, gen_lens) -> int:
         steps = g - 1
         total += steps * p + steps * (steps + 1) // 2
     return total
-
-
-def decode_step_bytes(m: Dims, rows: int, live_positions: float) -> float:
-    """Least bytes one chip reads in a decode step: every weight except the
-    embedding table (``rows`` of it are looked up), plus the live cache."""
-    weights = (param_count(m) - m.vocab_padded * m.d) * m.elem_bytes
-    lookup = rows * m.d * m.elem_bytes
-    return (weights + lookup) / m.chips \
-        + kv_bytes_per_token(m) * live_positions
-
-
-def prefill_step_bytes(m: Dims, rows: int, prompt_len: int) -> float:
-    """Least bytes one chip moves in a prefill of ``rows`` prompts: every
-    weight read once, the rows' cache written."""
-    weights = (param_count(m) - m.vocab_padded * m.d) * m.elem_bytes
-    return weights / m.chips + kv_bytes_per_token(m) * rows * prompt_len
 
 
 def roofline_s(flops: float, nbytes: float, peak: dict,
