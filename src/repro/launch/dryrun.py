@@ -11,9 +11,10 @@ For each cell:
   * ``jax.jit(...).lower(**ShapeDtypeStructs).compile()`` — no allocation,
   * record memory_analysis (per-device bytes: proves it fits),
   * record cost_analysis (flops/bytes as reported; see scan caveat),
-  * parse the partitioned HLO for collectives: op kind, operand bytes,
-    replica-group size, and the enclosing while-loop trip-count multiplier
-    (scan bodies execute trip-count times but appear once in HLO).
+  * parse the partitioned HLO for collectives (``repro.runtime.hlo``): op
+    kind, operand bytes, replica-group size, and the enclosing while-loop
+    trip-count multiplier (scan bodies execute trip-count times but appear
+    once in HLO).
 
 Usage:
   python -m repro.launch.dryrun --arch starcoder2-15b --shape train_4k \
@@ -23,7 +24,6 @@ Usage:
 
 import argparse
 import json
-import re
 import time
 import traceback
 from pathlib import Path
@@ -35,122 +35,12 @@ from repro.configs.shapes import (SHAPE_NAMES, config_for_shape, input_specs,
                                   shape_applicable)
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import bundle_for
-
-COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
-               "collective-permute")
-_DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s64": 8, "u64": 8,
-                "s32": 4, "u32": 4, "s16": 2, "u16": 2, "s8": 1, "u8": 1,
-                "pred": 1, "f8e4m3fn": 1, "f8e5m2": 1}
+from repro.runtime.hlo import _shape_bytes, parse_collectives  # noqa: F401
 
 
 def cost_analysis_dict(compiled) -> dict:
     """compiled.cost_analysis(), or {} when the backend reports none."""
     return compiled.cost_analysis() or {}
-
-
-def _shape_bytes(type_str: str) -> int:
-    m = re.match(r"([a-z0-9]+)\[([\d,]*)\]", type_str)
-    if not m:
-        return 0
-    dt, dims = m.group(1), m.group(2)
-    n = 1
-    if dims:
-        for d in dims.split(","):
-            n *= int(d)
-    return n * _DTYPE_BYTES.get(dt, 4)
-
-
-def parse_collectives(hlo: str) -> dict:
-    """Per-computation collective census with while-loop trip multipliers."""
-    # 1) split into computations. NOTE: computation headers may have tuple
-    # parameters with nested parens — the greedy `\(.*\)` handles them.
-    comp_re = re.compile(r"^(?:ENTRY )?%?([\w\.\-]+)\s*\(.*\)\s*->.*\{$")
-    comps: dict[str, list[str]] = {}
-    cur = None
-    for line in hlo.splitlines():
-        m = comp_re.match(line.strip())
-        if m:
-            cur = m.group(1)
-            comps[cur] = []
-        elif cur is not None:
-            comps[cur].append(line)
-
-    # 2) while nesting: comp -> (body_comp, cond_comp)
-    while_re = re.compile(r"while\(.*\).*condition=%?([\w\.\-]+),"
-                          r"\s*body=%?([\w\.\-]+)")
-    parent: dict[str, tuple[str, str]] = {}  # body -> (parent_comp, cond)
-    for cname, lines in comps.items():
-        for ln in lines:
-            m = while_re.search(ln)
-            if m:
-                parent[m.group(2)] = (cname, m.group(1))
-
-    # 3) trip counts from cond computations (largest s32 constant)
-    def trip_count(cond: str) -> int | None:
-        best = None
-        for ln in comps.get(cond, []):
-            for m in re.finditer(r"constant\((\d+)\)", ln):
-                v = int(m.group(1))
-                best = v if best is None else max(best, v)
-        return best
-
-    def multiplier(comp: str) -> int:
-        mult = 1
-        seen = set()
-        c = comp
-        while c in parent and c not in seen:
-            seen.add(c)
-            pcomp, cond = parent[c]
-            t = trip_count(cond)
-            mult *= t if t else 1
-            c = pcomp
-        return mult
-
-    # 4) collectives per computation
-    coll_re = re.compile(
-        r"=\s*([a-z0-9]+\[[\d,]*\])[^=]*\b(" + "|".join(COLLECTIVES)
-        + r")\(([^)]*)\)(.*)$")
-    group_re = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
-    ops = []
-    totals = {k: 0.0 for k in COLLECTIVES}
-    for cname, lines in comps.items():
-        mult = multiplier(cname)
-        for ln in lines:
-            m = coll_re.search(ln)
-            if not m:
-                continue
-            result_t, kind, operands, tail_txt = m.groups()
-            result_b = _shape_bytes(result_t)
-            gm = group_re.search(ln)
-            gsize = int(gm.group(2)) if gm else 1
-            if kind == "all-gather":
-                operand_b = result_b // max(gsize, 1)
-            elif kind == "reduce-scatter":
-                operand_b = result_b * gsize
-            else:
-                operand_b = result_b
-            # Ring-model wire bytes per device: all-reduce moves ~2x its
-            # operand (reduce-scatter phase + all-gather phase); the others
-            # move ~(g-1)/g x their payload (~1x).
-            if kind == "all-reduce":
-                eff = 2 * operand_b
-            elif kind == "all-gather":
-                eff = result_b  # (g-1)/g of the gathered result
-            else:
-                eff = operand_b
-            eff = int(eff * max(gsize - 1, 0) / max(gsize, 1))
-            ops.append({"kind": kind, "computation": cname,
-                        "operand_bytes": operand_b, "group_size": gsize,
-                        "multiplier": mult, "effective_bytes": eff})
-            totals[kind] += operand_b * mult
-    eff_totals = {}
-    for o in ops:
-        eff_totals[o["kind"]] = eff_totals.get(o["kind"], 0) \
-            + o["effective_bytes"] * o["multiplier"]
-    return {"ops": ops, "per_device_bytes_by_kind": totals,
-            "per_device_bytes_total": sum(totals.values()),
-            "effective_bytes_by_kind": eff_totals,
-            "effective_bytes_total": sum(eff_totals.values())}
 
 
 def run_cell(arch: str, shape: str, *, multi_pod: bool,

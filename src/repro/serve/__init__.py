@@ -368,6 +368,8 @@ def serve_workload(
                                 faults=faults, fault_lane=0,
                                 prefix_store=prefix_store,
                                 priority=cfg.priority, preempt=cfg.preempt)
+    if engine is not None:
+        batcher.metrics.decode_exchange_bytes = engine.decode_exchange_bytes
     out = batcher.run(requests)
     if out["orphans"]:
         # No fleet behind this path: a crash's orphans have nowhere to go.

@@ -140,6 +140,10 @@ class ServingEngine:
         self.runs_ahead = set(cfg.pattern) <= {"attn", "local"}
         self._armed: np.ndarray | None = None
         self._ahead: _RunAhead | None = None
+        # Per-device operand bytes of the collectives one decode step runs,
+        # read from its compiled HLO at warm-up
+        # (:meth:`_read_decode_exchange`); 0 on one device.
+        self.decode_exchange_bytes = 0
 
         with self.mesh:
             caches_abs = jax.eval_shape(
@@ -161,6 +165,7 @@ class ServingEngine:
                 dec.fn, in_shardings=dec.in_shardings,
                 out_shardings=dec.out_shardings,
                 donate_argnums=dec.donate_argnums)
+            self._dec_abstract = dec.abstract_args
             # Host tokens are placed as the step's ``next_token`` lies, so
             # that host and device tokens call one executable (an array not
             # committed to a sharding would call a second one).
@@ -303,6 +308,25 @@ class ServingEngine:
                 # A run-ahead's caches are live: free them before the next
                 # length's are made, or the two would count in the peak.
                 del caches
+        self._read_decode_exchange()
+
+    def _read_decode_exchange(self) -> None:
+        """Count, once, the bytes one decode step exchanges between devices:
+        the per-device operand bytes of the collectives in the compiled
+        step's HLO, each weighted by the trip counts of the loops around it
+        (``repro.runtime.hlo``).  Sets :attr:`decode_exchange_bytes` and puts
+        it on the tracer's ``setup`` track.  One device exchanges nothing,
+        and its step is not read."""
+        if self.mesh.size == 1:
+            return
+        from repro.runtime.hlo import parse_collectives
+        with self.mesh:
+            hlo = self._dec_jit.lower(*self._dec_abstract).compile().as_text()
+        self.decode_exchange_bytes = int(
+            parse_collectives(hlo)["per_device_bytes_total"])
+        self.tracer.counter("engine", "setup", "decode_exchange_bytes",
+                            time.perf_counter(), self.decode_exchange_bytes,
+                            domain="wall_s")
 
     def _lens(self, lens) -> np.ndarray:
         """Per-slot cache lengths as a (max_batch,) int32 vector."""

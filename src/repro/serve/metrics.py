@@ -104,6 +104,9 @@ class ServeMetrics:
     # Decode run-ahead (DESIGN.md §6): decode steps dispatched while the
     # previous decode was still in flight.
     decode_chained: int = 0
+    # Per-device bytes one decode step exchanges between devices, read from
+    # the compiled step at the engine's warm-up (0 on one device).
+    decode_exchange_bytes: int = 0
     # Energy accounting (DESIGN.md §11): joules attributed to completed
     # jobs, accumulated from the fabric's deterministic closed-form pricing
     # on every serving path identically.

@@ -64,6 +64,14 @@ def test_parse_collectives_trip_counts_and_bytes():
     assert ar["effective_bytes"] == int(2 * 256 * 1 / 2)
 
 
+def test_the_census_is_the_runtime_modules():
+    """The dry-run reads collectives with the census the serving engine
+    uses, defined once in ``repro.runtime.hlo``."""
+    from repro.runtime import hlo
+    assert parse_collectives is hlo.parse_collectives
+    assert _shape_bytes is hlo._shape_bytes
+
+
 @pytest.mark.slow
 def test_miniature_dryrun_cell_end_to_end():
     """Run the real dry-run path (steps + shardings + compile + analysis)
